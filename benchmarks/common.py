@@ -45,13 +45,34 @@ def record_metrics(name: str, snapshot: dict) -> None:
     }
 
 
+def run_sharded(module: str, main: Callable[[int, int], None], rows: int,
+                devices: int) -> None:
+    """Entry of a sharded benchmark under ``benchmarks.run``.  On the CPU
+    the module re-execs with ``devices`` simulated host devices; on an
+    accelerator it runs here, on the devices that exist — a child process
+    would need the chip this process already holds.  With one accelerator
+    there is no sharded-vs-single comparison to make (each module asserts
+    a >= 2x speedup), so the module is skipped with a printed reason."""
+    if jax.default_backend() == "cpu":
+        rerun_with_simulated_devices(module, rows, devices)
+    elif len(jax.devices()) < 2:
+        print(f"{module}: skipped, its sharded-vs-single-device comparison "
+              f"needs >= 2 devices, found {len(jax.devices())}",
+              file=sys.stderr)
+    else:
+        main(rows, len(jax.devices()))
+
+
 def rerun_with_simulated_devices(module: str, rows: int, devices: int,
                                  timeout: int = 1200) -> None:
     """Re-exec a sharded benchmark module in a child process with
     ``xla_force_host_platform_device_count`` set in its environment (jax
     only honors the flag before import, and the parent driver already
     initialized jax), folding the child's printed CSV rows back into
-    ``ROWS`` so ``--json`` exports see them."""
+    ``ROWS`` so ``--json`` exports see them.  CPU only: the child starts
+    its own backend."""
+    assert jax.default_backend() == "cpu", \
+        "simulated devices are a CPU-backend rehearsal"
     env = dict(os.environ)
     env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "")
                         + f" --xla_force_host_platform_device_count"

@@ -70,6 +70,9 @@ def main() -> int:
                          "(bench-trajectory CI artifact)")
     args = ap.parse_args()
 
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
+
     from . import (continuous_batching, fig2a_projection_pushdown,
                    fig2b_clustering, fig2c_inlining, fig2d_nn_translation,
                    fig2d_tree_gemm, fig3_integration, lossy_pushdown,
@@ -80,9 +83,9 @@ def main() -> int:
     n = 30_000 if args.quick else 200_000
     print("name,us_per_call,derived")
     jobs = [
-        # the sharded benchmarks re-exec themselves with 8 simulated
-        # devices; run them FIRST, while this parent process is still
-        # small — their child processes assert wall-clock speedups, and
+        # on the CPU the sharded benchmarks re-exec themselves with 8
+        # simulated devices; run them FIRST, while this parent process is
+        # still small — their child processes assert wall-clock speedups, and
         # a parent bloated by the earlier benchmarks' jax allocations
         # steals enough of a small CI machine to flake those asserts
         ("sharded_scan", lambda: sharded_scan.run(n_rows=n)),

@@ -46,13 +46,9 @@ SQL_SELECTIVE = SQL_FULL + " WHERE age < 25"
 
 
 def run(n_rows: int = 200_000, devices: int = 8) -> None:
-    """Driver entry (``benchmarks.run``): jax in this process already owns
-    its devices, so re-exec this module with the simulated-device flag set
-    in the child's environment and fold its CSV rows back into
-    ``common.ROWS`` (so ``--json`` exports see them)."""
-    from .common import rerun_with_simulated_devices
-    rerun_with_simulated_devices("benchmarks.sharded_scan", n_rows,
-                                 devices)
+    """Entry from ``benchmarks.run``: see ``common.run_sharded``."""
+    from .common import run_sharded
+    run_sharded("benchmarks.sharded_scan", main, n_rows, devices)
 
 
 def _build_store(n_rows: int):
@@ -136,7 +132,7 @@ def main(n_rows: int, devices: int) -> None:
     morsel_rows = pow2_bucket(-(-n_rows // N_PARTITIONS))
     import jax
     assert len(jax.devices()) >= devices, \
-        f"need {devices} simulated devices, found {len(jax.devices())}"
+        f"need {devices} devices, found {len(jax.devices())}"
 
     from repro.serve import PredictionService
     from repro.core import OptimizerConfig, ExecutionConfig

@@ -54,13 +54,9 @@ EXTERNAL_LATENCY_S = 25e-3
 
 
 def run(n_rows: int = 200_000, devices: int = 8) -> None:
-    """Driver entry (``benchmarks.run``): jax in this process already owns
-    its devices, so re-exec with the simulated-device flag set in the
-    child's environment and fold its CSV rows back into ``common.ROWS``
-    (so ``--json`` exports see them)."""
-    from .common import rerun_with_simulated_devices
-    rerun_with_simulated_devices("benchmarks.shuffle_join", n_rows,
-                                 devices)
+    """Entry from ``benchmarks.run``: see ``common.run_sharded``."""
+    from .common import run_sharded
+    run_sharded("benchmarks.shuffle_join", main, n_rows, devices)
 
 
 def _build_store(n_rows: int):
@@ -185,7 +181,7 @@ def main(n_rows: int, devices: int) -> None:
     morsel_rows = pow2_bucket(-(-n_rows // devices))
     import jax
     assert len(jax.devices()) >= devices, \
-        f"need {devices} simulated devices, found {len(jax.devices())}"
+        f"need {devices} devices, found {len(jax.devices())}"
 
     # unsharded reference (one whole-table execution, single model hop)
     ref = _service(store, 1, morsel_rows, sharded=False)

@@ -437,7 +437,8 @@ def compile_plan(plan: Plan, catalog,
                 env[nid] = ins[0] * jnp.asarray(a["scale"]) \
                     + jnp.asarray(a["offset"])
             elif op == "matmul_bias":
-                env[nid] = ins[0] @ jnp.asarray(a["weights"]) \
+                env[nid] = jnp.dot(ins[0], jnp.asarray(a["weights"]),
+                                   precision=jax.lax.Precision.HIGHEST) \
                     + jnp.asarray(a["bias"])
             elif op == "sigmoid":
                 env[nid] = jax.nn.sigmoid(ins[0])

@@ -248,7 +248,13 @@ def choose_tree_impl(model, n_rows: float, n_features: int,
 # --------------------------------------------------------------------------
 
 _CAL_TREES, _CAL_DEPTH, _CAL_FEATURES = 8, 6, 8
-_CAL_SIZES = (512, 8192)
+# The small size fixes the per-call cost; the large one must be past the
+# caches, where the per-row cost settles.  On a v5e, a fit at 512 and 8192
+# rows put traversal at 1.25e-8 s per row-tree-step, where 10M rows read
+# 5.3e-8 s; that 4x underestimate sent a 10M-row scoring to traversal at
+# some 20x the dense strategy's time.  A fit at 512 and 131,072 rows reads
+# 2.9e-8 s: still about 1.8x under, but enough to pick the dense strategy.
+_CAL_SIZES = (512, 1 << 17)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -331,7 +337,7 @@ def measure_tree_calibration(backend: Optional[str] = None
             jax.jit(lambda v: predict_ensemble_gemm(ens, v)), xs)
         if backend == "tpu":
             times[("pallas", n)] = _time_call(
-                lambda v: tg_ops.tree_gemm(ens, v, interpret=False), xs)
+                lambda v: tg_ops.tree_gemm(ens, v), xs)
 
     n0, n1 = _CAL_SIZES
     step, trav_call = _fit_linear(n0, times[("trav", n0)],

@@ -29,17 +29,16 @@ def _measured_strategy(n, plan, catalog, cfg, report) -> None:
     annotation still lands here so the plan records an honest decision."""
     if n.attrs.get("tree_strategy") is not None:
         return
-    try:
-        from ..cost_model import choose_tree_strategy, estimate_rows
-        rows = estimate_rows(plan, catalog)
-        n_rows = rows.get(n.inputs[0], 1e6) if n.inputs else 1e6
-        model = n.attrs["model"]
-        t0 = model.tree if model.kind == "decision_tree" else model.trees[0]
-        n_feat = int(t0.n_features)
-        strategy, costs = choose_tree_strategy(model, n_rows, n_feat,
-                                               catalog=catalog)
-    except Exception:      # calibration must never break optimization
-        return
+    from ..cost_model import choose_tree_strategy, estimate_rows
+    rows = estimate_rows(plan, catalog)
+    n_rows = rows.get(n.inputs[0], 1e6) if n.inputs else 1e6
+    model = n.attrs["model"]
+    t0 = model.tree if model.kind == "decision_tree" else model.trees[0]
+    n_feat = int(t0.n_features)
+    # a calibration that fails (say, a kernel the device refuses) fails
+    # the query: a swallowed error would hide a device fault
+    strategy, costs = choose_tree_strategy(model, n_rows, n_feat,
+                                           catalog=catalog)
     n.attrs["tree_strategy"] = strategy
     if strategy != "traversal":
         report.log("runtime_selection",

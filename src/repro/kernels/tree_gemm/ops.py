@@ -12,10 +12,6 @@ from .tree_gemm import tree_gemm_pallas
 __all__ = ["tree_gemm"]
 
 
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
-
-
 @functools.partial(jax.jit, static_argnames=("average", "n_trees",
                                              "interpret"))
 def _run(x, a, b, c, d, e, n_trees: int, average: bool, interpret: bool):
@@ -31,15 +27,13 @@ def _run(x, a, b, c, d, e, n_trees: int, average: bool, interpret: bool):
     return out / n_trees if average else out
 
 
-def tree_gemm(ensemble, x: jnp.ndarray, interpret: bool = None
-              ) -> jnp.ndarray:
+def tree_gemm(ensemble, x: jnp.ndarray) -> jnp.ndarray:
     """Score an ``repro.ml.hummingbird.EnsembleGemm`` with the Pallas kernel.
 
-    On non-TPU backends runs in interpret mode (Pallas executes the kernel
-    body in Python) — correctness-identical, used by tests.
+    Compiled by Mosaic on every backend but the CPU, where the kernel body
+    runs in interpret mode (same results; what the tests exercise).
     """
-    if interpret is None:
-        interpret = not _on_tpu()
+    interpret = jax.default_backend() == "cpu"
     return _run(x, jnp.asarray(ensemble.a), jnp.asarray(ensemble.b),
                 jnp.asarray(ensemble.c), jnp.asarray(ensemble.d),
                 jnp.asarray(ensemble.e), n_trees=ensemble.n_trees,

@@ -17,11 +17,20 @@ Grid: (n_row_blocks, n_trees).  VMEM per cell (defaults, F<=512, I=L=128,
 O<=128): X block 128xF (256 KB) + A Fx128 + C 128x128 + E 128xO + scratch
 (~0.5 MB total) — comfortably inside the ~16 MB v5e VMEM budget even with
 double buffering.
+
+The per-tree vectors ``b [T, I]`` and ``d [T, L]`` enter as ``[T, 1, I]``
+and ``[T, 1, L]``: Mosaic requires a block's last two dims to be multiples
+of (8, 128) or the full array dims, and a ``(1, I)`` block of a ``[T, I]``
+array is neither, while ``(1, 1, I)`` of ``[T, 1, I]`` is.
+
+Precision: at the default precision the MXU rounds f32 operands to bf16.
+``X A`` must reproduce each feature exactly (a rounded feature can flip a
+gate and pick another leaf) and ``match E`` must return the exact leaf
+value, so both run at ``HIGHEST``.  ``gates C`` multiplies {0, 1} by
+{-1, 0, +1}, which bf16 holds exactly, so it stays at the default.
 """
 
 from __future__ import annotations
-
-import functools
 
 import jax
 import jax.numpy as jnp
@@ -37,17 +46,19 @@ def tree_gemm_kernel(x_ref, a_ref, b_ref, c_ref, d_ref, e_ref, o_ref):
     d [1, L]; select e [L, O] row; accumulate into o [BR, O].
     """
     t_idx = pl.program_id(1)
+    exact = jax.lax.Precision.HIGHEST
 
     x = x_ref[...]
     a = a_ref[0]                                            # [F, I]
-    xa = jax.lax.dot(x, a, preferred_element_type=jnp.float32)
-    gates = (xa <= b_ref[...]).astype(jnp.float32)          # [BR, I]
+    xa = jax.lax.dot(x, a, precision=exact,
+                     preferred_element_type=jnp.float32)
+    gates = (xa <= b_ref[0]).astype(jnp.float32)            # [BR, I]
     s = jax.lax.dot(gates, c_ref[0],
                     preferred_element_type=jnp.float32)     # [BR, L]
-    match = (s == d_ref[...]).astype(jnp.float32)           # [BR, L]
+    match = (s == d_ref[0]).astype(jnp.float32)             # [BR, L]
     # onehot(argmax(match)) == match when exactly one leaf matches (padded
     # leaves carry D=+inf so they never match): the select is one more GEMM.
-    out = jax.lax.dot(match, e_ref[0],
+    out = jax.lax.dot(match, e_ref[0], precision=exact,
                       preferred_element_type=jnp.float32)   # [BR, O]
 
     @pl.when(t_idx == 0)
@@ -78,13 +89,13 @@ def tree_gemm_pallas(x, a, b, c, d, e, *, block_rows: int = 128,
         in_specs=[
             pl.BlockSpec((block_rows, f), lambda r, ti: (r, 0)),
             pl.BlockSpec((1, f, i), lambda r, ti: (ti, 0, 0)),
-            pl.BlockSpec((1, i), lambda r, ti: (ti, 0)),
+            pl.BlockSpec((1, 1, i), lambda r, ti: (ti, 0, 0)),
             pl.BlockSpec((1, i, l), lambda r, ti: (ti, 0, 0)),
-            pl.BlockSpec((1, l), lambda r, ti: (ti, 0)),
+            pl.BlockSpec((1, 1, l), lambda r, ti: (ti, 0, 0)),
             pl.BlockSpec((1, l, o), lambda r, ti: (ti, 0, 0)),
         ],
         out_specs=pl.BlockSpec((block_rows, o), lambda r, ti: (r, 0)),
         out_shape=jax.ShapeDtypeStruct((n_pad, o), jnp.float32),
         interpret=interpret,
-    )(x, a, b, c, d, e)
+    )(x, a, b[:, None, :], c, d[:, None, :], e)
     return out[:n]

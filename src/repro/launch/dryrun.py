@@ -200,15 +200,7 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool,
     compiled = lowered.compile()
     t_compile = time.time() - t0
 
-    # cost_analysis() returns a flat dict on new JAX, a one-per-computation
-    # list of dicts on older releases.
-    raw_cost = compiled.cost_analysis() or {}
-    if isinstance(raw_cost, (list, tuple)):
-        flat_cost = {}
-        for entry in raw_cost:
-            flat_cost.update(entry)
-    else:
-        flat_cost = dict(raw_cost)
+    flat_cost = dict(compiled.cost_analysis() or {})
     try:
         mem = compiled.memory_analysis()
         memory = {

@@ -18,17 +18,8 @@ __all__ = ["make_production_mesh", "make_local_mesh", "make_data_mesh"]
 
 
 def _make_mesh(shape, axes):
-    """``jax.make_mesh`` across JAX versions: ``axis_types`` (and
-    ``jax.sharding.AxisType``) only exist in newer releases; Auto is the
-    default there, so omitting the argument on old JAX is equivalent."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is not None:
-        try:
-            auto = (axis_type.Auto,) * len(axes)
-            return jax.make_mesh(shape, axes, axis_types=auto)
-        except TypeError:      # make_mesh predates the axis_types kwarg
-            pass
-    return jax.make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):

@@ -108,7 +108,13 @@ class StandardScaler:
     def transform(self, columns: Dict[str, jnp.ndarray]) -> jnp.ndarray:
         mat = jnp.stack([jnp.asarray(columns[c], jnp.float32)
                          for c in self.columns], axis=1)
-        return (mat - jnp.asarray(self.mean)) / jnp.asarray(self.std)
+        # Multiply by the float32 reciprocal rather than divide: XLA turns a
+        # divide by a constant into exactly this multiply inside a jitted
+        # plan but not in an eager call, so a divide would give features
+        # that differ by an ulp between fit and serve and from a host
+        # reference.  The multiply is the same float32 operation everywhere.
+        inv = np.float32(1.0) / self.std
+        return (mat - jnp.asarray(self.mean)) * jnp.asarray(inv)
 
     # LA form (for NN translation): x*a + b
     def affine(self) -> Tuple[np.ndarray, np.ndarray]:

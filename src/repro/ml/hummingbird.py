@@ -25,10 +25,17 @@ from __future__ import annotations
 import dataclasses
 from typing import List, Optional, Sequence, Tuple
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
 from .tree import TreeArrays
+
+# On the TPU an f32 matmul at default precision rounds its operands to bf16:
+# a rounded feature can flip a gate and a rounded leaf value is a wrong
+# payout, so those dots run at HIGHEST.  ``gates @ C`` multiplies {0, 1}
+# by {-1, 0, +1}, which bf16 holds exactly, and keeps the default.
+_EXACT = jax.lax.Precision.HIGHEST
 
 __all__ = ["TreeGemm", "EnsembleGemm", "tree_to_gemm", "ensemble_to_gemm",
            "ensemble_to_gemm_mxu", "predict_gemm", "predict_ensemble_gemm"]
@@ -91,7 +98,8 @@ def tree_to_gemm(tree: TreeArrays) -> TreeGemm:
 
 def predict_gemm(g: TreeGemm, x: jnp.ndarray) -> jnp.ndarray:
     """Pure-jnp oracle for the GEMM strategy."""
-    t = (x @ jnp.asarray(g.a) <= jnp.asarray(g.b)).astype(jnp.float32)
+    t = (jnp.dot(x, jnp.asarray(g.a), precision=_EXACT)
+         <= jnp.asarray(g.b)).astype(jnp.float32)
     s = t @ jnp.asarray(g.c)
     match = (s == jnp.asarray(g.d)).astype(jnp.float32)
     # Exactly one leaf matches; argmax picks it.
@@ -171,8 +179,6 @@ def predict_ensemble_gemm(ens: EnsembleGemm, x: jnp.ndarray) -> jnp.ndarray:
     value plus exact zeros; trees accumulate sequentially in tree order and
     divide by n_trees last — the same float32 operation sequence as traversal.
     """
-    import jax
-
     b = jnp.asarray(ens.b)
     c = jnp.asarray(ens.c)
     d = jnp.asarray(ens.d)
@@ -186,12 +192,13 @@ def predict_ensemble_gemm(ens: EnsembleGemm, x: jnp.ndarray) -> jnp.ndarray:
         a = jnp.asarray(ens.a)
 
         def gate(t):
-            return (x @ a[t] <= b[t]).astype(jnp.float32)
+            return (jnp.dot(x, a[t], precision=_EXACT)
+                    <= b[t]).astype(jnp.float32)
 
     def one_tree(t):
         s = gate(t) @ c[t]                            # [n, L] exact ints
         match = (s == d[t]).astype(jnp.float32)
-        return match @ e[t]                           # [n, O]
+        return jnp.dot(match, e[t], precision=_EXACT)  # [n, O]
 
     acc = jax.lax.fori_loop(
         1, ens.n_trees, lambda t, acc: acc + one_tree(t), one_tree(0))

@@ -15,6 +15,12 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+# On the TPU an f32 matmul at default precision rounds its operands to bf16.
+# A v5e computed this model's matrix-vector dots exactly at the default as
+# well (one output column, no bf16 pass), so HIGHEST changes no answer
+# today; it keeps the f32 answer should the dot ever go through the MXU.
+_EXACT = jax.lax.Precision.HIGHEST
+
 __all__ = ["LinearRegression", "LogisticRegression"]
 
 
@@ -78,14 +84,15 @@ class _LinearBase:
         return clone
 
     def decision_function(self, x: jnp.ndarray) -> jnp.ndarray:
-        return jnp.asarray(x, jnp.float32) @ jnp.asarray(self.weights) + self.bias
+        return jnp.dot(jnp.asarray(x, jnp.float32), jnp.asarray(self.weights),
+                       precision=_EXACT) + self.bias
 
 
 class LinearRegression(_LinearBase):
     kind = "linear_regression"
 
     def _objective(self, w, b, x, y):
-        pred = x @ w + b
+        pred = jnp.dot(x, w, precision=_EXACT) + b
         return jnp.mean((pred - y) ** 2)
 
     def predict(self, x: jnp.ndarray) -> jnp.ndarray:
@@ -96,7 +103,7 @@ class LogisticRegression(_LinearBase):
     kind = "logistic_regression"
 
     def _objective(self, w, b, x, y):
-        logits = x @ w + b
+        logits = jnp.dot(x, w, precision=_EXACT) + b
         return jnp.mean(jnp.maximum(logits, 0) - logits * y
                         + jnp.log1p(jnp.exp(-jnp.abs(logits))))
 

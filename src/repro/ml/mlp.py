@@ -8,6 +8,10 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+# On the TPU an f32 matmul at default precision rounds its operands to bf16,
+# which moves scores off the f32 reference; model dots run at HIGHEST.
+_EXACT = jax.lax.Precision.HIGHEST
+
 __all__ = ["MLP"]
 
 
@@ -41,7 +45,7 @@ class MLP:
     def apply(params, x):
         h = x
         for i, layer in enumerate(params):
-            h = h @ layer["w"] + layer["b"]
+            h = jnp.dot(h, layer["w"], precision=_EXACT) + layer["b"]
             if i < len(params) - 1:
                 h = jax.nn.relu(h)
         return h

@@ -30,19 +30,8 @@ __all__ = ["moe_params", "moe_apply", "moe_apply_sharded", "moe_reference"]
 
 
 def _shard_map(f, mesh, in_specs, out_specs):
-    """shard_map across JAX versions: new releases expose ``jax.shard_map``
-    with ``check_vma``; older ones have ``jax.experimental.shard_map`` with
-    ``check_rep``."""
-    if hasattr(jax, "shard_map"):
-        try:
-            return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                                 out_specs=out_specs, check_vma=False)
-        except TypeError:
-            return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                                 out_specs=out_specs, check_rep=False)
-    from jax.experimental.shard_map import shard_map as sm
-    return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-              check_rep=False)
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 def moe_params(cfg) -> Dict:

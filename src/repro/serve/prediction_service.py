@@ -99,7 +99,8 @@ import dataclasses
 import threading
 import time
 import weakref
-from typing import (Any, Dict, List, Mapping, Optional, Set, Tuple, Union)
+from typing import (Any, Dict, List, Mapping, Optional, Sequence, Set, Tuple,
+                    Union)
 
 import jax
 import jax.numpy as jnp
@@ -324,7 +325,6 @@ class CompiledPrediction:
     report: OptimizationReport
     fn: Any                          # (tables dict) -> Table | array
     scan_tables: Tuple[str, ...]
-    chunk_table: Optional[str]       # set iff the plan is row-local/chunkable
     compile_time_s: float = 0.0
     serves: int = 0
     model_names: Tuple[str, ...] = ()
@@ -344,6 +344,16 @@ class CompiledPrediction:
     # (partition-wise joins / two-phase aggregation); None for row-local
     # and whole-table plans.
     dist: Optional[DistributedSpec] = None
+    # Table the plan can run in row morsels of (``chunk_rows``): its
+    # output rows are this table's rows one for one (see _morsel_table).
+    morsel_table: Optional[str] = None
+
+    @property
+    def chunk_table(self) -> Optional[str]:
+        """``morsel_table`` when the plan scans no other table, i.e. it is
+        row-local end to end, as partition sharding and request stacking
+        need."""
+        return self.morsel_table if len(self.scan_tables) == 1 else None
 
 
 class PredictionTicket:
@@ -566,6 +576,34 @@ def _round_up(n: int, multiple: int) -> int:
 # ---------------------------------------------------------------------------
 # Plan introspection for the result-cache tier.
 # ---------------------------------------------------------------------------
+
+def _morsel_table(plan: Plan, aligned_roots: Sequence[str]) -> Optional[str]:
+    """The table whose rows ``plan.output`` and every ``aligned_roots``
+    node are, one for one, or None.  Row-local ops keep their input's
+    rows.  An N:1 lookup join keeps its probe (left) side's rows and reads
+    its build (right) side whole, so a morsel of the probe table joined
+    with the whole build table gives exactly that morsel's rows of the
+    whole-table result — unless the build side also scans the probe
+    table, whose morsel would then replace it there too.  Any other op
+    mixes rows."""
+    rows_of: Dict[str, Optional[str]] = {}
+    for nid in plan.topo_order():
+        n = plan.nodes[nid]
+        if n.op == "scan":
+            rows_of[nid] = n.attrs["table"]
+        elif n.op == "join":
+            probe = rows_of[n.inputs[0]]
+            build = subtree_nodes(plan, n.inputs[1])
+            rows_of[nid] = None if probe in _scan_names(plan, build) \
+                else probe
+        elif n.op in _ROW_LOCAL_OPS:
+            tables = {rows_of[i] for i in n.inputs}
+            rows_of[nid] = tables.pop() if len(tables) == 1 else None
+        else:
+            rows_of[nid] = None
+    tables = {rows_of[plan.output]} | {rows_of[r] for r in aligned_roots}
+    return tables.pop() if len(tables) == 1 else None
+
 
 def _scan_names(plan: Plan, nids=None) -> Tuple[str, ...]:
     nodes = [plan.nodes[i] for i in nids] if nids is not None \
@@ -1496,19 +1534,19 @@ class PredictionService:
                                   if capture_ref is not None else None)
             fn = self._jit(raw_fn)
         scans = _scan_names(exec_plan)
-        chunk_table = None
-        if len(scans) == 1 and all(n.op in _ROW_LOCAL_OPS
-                                   for n in exec_plan.nodes.values()):
-            chunk_table = scans[0]
+        morsel_table = _morsel_table(
+            exec_plan, [capture_ref.subtree_plan.output]
+            if capture_ref is not None else [])
         dist = None
         if splice_ref is None:
             dist = self._distributed_spec(exec_plan, overridden, raw_fn)
         compile_time = time.perf_counter() - t0
         compiled = CompiledPrediction(
             key=key, signature=sig, plan=exec_plan, report=report, fn=fn,
-            scan_tables=scans, chunk_table=chunk_table,
+            scan_tables=scans,
             compile_time_s=compile_time, model_names=model_names,
             capture=capture_ref, splice=splice_ref, raw_fn=raw_fn,
+            morsel_table=morsel_table,
             catalog_versions=tuple((t, self._table_version(t))
                                    for t in full_scans),
             dist=dist)
@@ -1725,7 +1763,6 @@ class PredictionService:
         compiled = CompiledPrediction(
             key=key, signature=hit.signature, plan=residual,
             report=hit.report, fn=fn, scan_tables=_scan_names(residual),
-            chunk_table=None,
             compile_time_s=hit.compile_time_s + time.perf_counter() - t0,
             model_names=hit.model_names, capture=None, splice=ref,
             raw_fn=raw_fn)
@@ -1911,8 +1948,8 @@ class PredictionService:
         elif not params and self._should_shard(compiled, tables):
             out = self._execute_sharded(compiled, tabs, store_capture,
                                         tenant=tenant, trace=trace)
-        elif (self.chunk_rows and compiled.chunk_table is not None
-                and tabs[compiled.chunk_table].capacity > self.chunk_rows):
+        elif (self.chunk_rows and compiled.morsel_table is not None
+                and tabs[compiled.morsel_table].capacity > self.chunk_rows):
             out = self._execute_chunked(compiled, tabs, store_capture,
                                         tenant=tenant, trace=trace)
         else:
@@ -2405,6 +2442,8 @@ class PredictionService:
                 if executor is not None else None,
                 "mesh_shape": executor.mesh_shape
                 if executor is not None else None,
+                "morsels_per_device": list(executor.morsels_per_device)
+                if executor is not None else None,
                 "sharded_executions": s.sharded_executions,
                 "shard_compiles": s.shard_compiles,
                 "shard_hits": s.shard_hits,
@@ -2564,8 +2603,9 @@ class PredictionService:
                          tenant: Optional[str] = None,
                          trace: Any = NULL_TRACE) -> Any:
         """Morsel execution: every chunk (tail included, via padding) has the
-        same static shape, so XLA compiles one chunk executable total."""
-        name = compiled.chunk_table
+        same static shape, so XLA compiles one chunk executable total.
+        Other tables (a lookup join's build side) go in whole."""
+        name = compiled.morsel_table
         table = tabs[name]
         n = table.capacity
         trace.event("chunked", rows=n, chunk_rows=self.chunk_rows)
@@ -2582,8 +2622,9 @@ class PredictionService:
             with self._lock:
                 self.stats.chunks_executed += 1
         if compiled.capture is not None and captured and store_capture:
-            # chunk_table plans are row-local end to end, so chunked capture
-            # concatenates to exactly the whole-table subtree value
+            # morsel_table holds for the capture root too, so its rows are
+            # the morsel's rows and the pieces concatenate to exactly the
+            # whole-table subtree value
             cap = jax.block_until_ready(
                 _trim_rows(_concat_outputs(captured), n))
             self._store_result(compiled.capture, cap,

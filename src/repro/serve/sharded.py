@@ -195,6 +195,8 @@ class ShardedExecutor:
         self.devices: List[Any] = list(np.asarray(mesh.devices).reshape(-1))
         self.mesh_shape: Tuple[int, ...] = tuple(
             np.asarray(mesh.devices).shape)
+        # morsels executed on each device (each worker bumps its own slot)
+        self.morsels_per_device: List[int] = [0] * len(self.devices)
 
     @property
     def n_devices(self) -> int:
@@ -353,6 +355,7 @@ class ShardedExecutor:
             for morsel, tables in prepared[d]:
                 t0 = trace.clock.monotonic() if live else 0.0
                 out = run_morsel(morsel, tables)
+                self.morsels_per_device[d] += 1
                 if live:
                     trace.add_span("shard_wave", t0,
                                    trace.clock.monotonic(), tid=d + 1,

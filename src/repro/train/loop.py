@@ -22,6 +22,18 @@ from .train_state import init_train_state, make_train_step
 
 __all__ = ["TrainLoopConfig", "train"]
 
+# Compute/communication overlap (async collectives) for the step's compile
+# on a TPU.  They go in as compile options, not as XLA_FLAGS: XLA reads
+# XLA_FLAGS once, when the backend starts, before it can be asked what it
+# is.  Every key must be one libtpu knows, or the compile fails
+# (tests/test_tpu_compile.py compiles with them for a described v5e).
+_TPU_OPTIONS = {
+    "xla_tpu_enable_async_collective_fusion": "true",
+    "xla_tpu_enable_async_collective_fusion_fuse_all_gather": "true",
+    "xla_tpu_overlap_compute_collective_tc": "true",
+    "xla_enable_async_all_gather": "true",
+}
+
 
 @dataclasses.dataclass
 class TrainLoopConfig:
@@ -53,7 +65,9 @@ def train(model, shape, loop_cfg: TrainLoopConfig,
 
     step_fn = make_train_step(model, loop_cfg.opt,
                               grad_accum=loop_cfg.grad_accum)
-    jit_step = jax.jit(step_fn, donate_argnums=(0,))
+    jit_step = jax.jit(step_fn, donate_argnums=(0,),
+                       compiler_options=_TPU_OPTIONS
+                       if jax.default_backend() == "tpu" else None)
 
     losses = []
 

@@ -27,6 +27,7 @@ from repro.ml import DecisionTree, Pipeline, PipelineMetadata, StandardScaler
 from repro.relational.table import Table
 from repro.relational.expr import col
 from repro.serve import PredictionService
+from repro.serve.prediction_service import _morsel_table
 
 N_ROWS = 600
 FEATS = ["age", "gender", "pregnant", "rcount"]
@@ -293,15 +294,44 @@ def test_chunked_single_plan_compile(store):
 
 
 def test_join_query_falls_back_to_whole_table(store):
+    """A join whose build side scans the probe table too has no morsel
+    table (a morsel would replace both sides), so it runs whole."""
+    plan = Plan()
+    probe = plan.add(Node("scan", Category.RA, [], {"table": "patient_info"},
+                          "table"))
+    build = plan.add(Node("scan", Category.RA, [], {"table": "patient_info"},
+                          "table"))
+    plan.output = plan.add(Node("join", Category.RA, [probe, build],
+                                {"on": "pid"}, "table"))
+    assert _morsel_table(plan, []) is None
+    service = PredictionService(store, chunk_rows=64)
+    compiled = service.compile(plan)
+    assert compiled.morsel_table is None and compiled.chunk_table is None
+    out = service.run(plan)
+    assert service.stats.chunks_executed == 0
+    assert np.asarray(out.valid).all()
+
+
+def test_lookup_join_runs_in_morsels_bitwise(store):
+    """A lookup join keeps its probe side's rows, so it runs in morsels of
+    the probe table with the build table whole, and matches the
+    whole-table answer bitwise."""
     # hematocrit keeps the join alive through join-elimination
     sql = ("SELECT pid, hematocrit FROM patient_info JOIN blood_tests ON pid "
            "WHERE age > 30")
     service = PredictionService(store, chunk_rows=64)
     compiled = service.compile(sql)
     assert compiled.chunk_table is None      # join is not row-local
+    assert compiled.morsel_table == "patient_info"
     out = service.run(sql)
-    assert service.stats.chunks_executed == 0
+    assert service.stats.chunks_executed == -(-N_ROWS // 64)
     assert np.asarray(out.valid).any()
+    want = PredictionService(store).run(sql)
+    c1, v1 = _table_arrays(out)
+    c2, v2 = _table_arrays(want)
+    assert (v1 == v2).all()
+    for k in c2:
+        assert (c1[k] == c2[k]).all(), k
 
 
 # ---------------------------------------------------------------------------

@@ -84,7 +84,7 @@ def _assert_bitwise(seed, n_trees, depth, n_features, dtype_kind, nan_frac):
         lambda v: predict_ensemble_gemm(ens8, v))(xj))
     mxu = np.asarray(jax.jit(
         lambda v: predict_ensemble_gemm(ens128, v))(xj))
-    pallas = np.asarray(tg_ops.tree_gemm(ens128, xj, interpret=True))
+    pallas = np.asarray(tg_ops.tree_gemm(ens128, xj))
 
     np.testing.assert_array_equal(want, dense)
     np.testing.assert_array_equal(want, mxu)
@@ -155,7 +155,7 @@ def test_crossover_not_worse_than_runner_up():
     }
     chosen, costs = choose_tree_strategy(rf, n, 8)
     if chosen == "pallas":              # only chosen on a real TPU
-        fns["pallas"] = lambda v: tg_ops.tree_gemm(ens, v, interpret=False)
+        fns["pallas"] = lambda v: tg_ops.tree_gemm(ens, v)
     # a single noisy sample (GC pause, CI neighbor) shouldn't fail the
     # build: re-measure up to 3 times and accept any clean round
     for attempt in range(3):
