@@ -12,14 +12,19 @@ partitioned scan through one :class:`PredictionService`, then shows:
    warm trace (executable-cache hit), and the second query of the
    shared-prefix pair whose trace visibly contains the
    ``result_cache_splice`` span — the cross-query cache at work.
-3. ``service.export_traces(path)`` — Chrome-trace JSON for
-   chrome://tracing or https://ui.perfetto.dev.
+3. A profiler trace (``jax.profiler.trace``): the same spans, named
+   ``repro.<phase>``, on the profiler's host timeline beside the device
+   ops; open the ``.xplane.pb`` it writes in TensorBoard's profiler, or
+   its ``perfetto_trace.json.gz`` in https://ui.perfetto.dev.
 4. ``service.metrics_text()`` — the Prometheus exposition unifying
    ServiceStats counters, cache gauges and latency histograms.
 
 Run:  PYTHONPATH=src python examples/explain_analyze.py
 """
 
+import tempfile
+
+import jax
 import numpy as np
 
 from repro.core import ModelStore
@@ -93,11 +98,13 @@ def main():
           f"(result_hits={service.stats.result_hits}, "
           f"spliced_executions={service.stats.spliced_executions})")
 
-    # -- 3. Chrome-trace export ------------------------------------------
-    path = "/tmp/repro_traces.json"
-    doc = service.export_traces(path)
-    print(f"\nwrote {len(doc['traceEvents'])} trace events to {path} "
-          "(load in chrome://tracing or https://ui.perfetto.dev)")
+    # -- 3. the same spans on the profiler's timeline ---------------------
+    log_dir = tempfile.mkdtemp(prefix="repro_profile_")
+    with jax.profiler.trace(log_dir, create_perfetto_trace=True):
+        service.run(SQL_B)
+    print(f"\nwrote a profiler trace under {log_dir} (repro.* host spans "
+          "beside the device ops; open perfetto_trace.json.gz in "
+          "https://ui.perfetto.dev)")
 
     # -- 4. the metrics registry -----------------------------------------
     print("\n" + "=" * 72)

@@ -306,6 +306,18 @@ def _external_predict(model, task: str, proba: bool, latency_s: float):
     return call
 
 
+# Name scope of each op's device ops (``jax.named_scope``), so a profiler
+# trace attributes device time to the layer that emitted it; every other
+# op is ``repro.relational``.  HLO metadata only: fusion is unchanged.
+_SCOPES = {"join": "repro.join", "udf": "repro.udf"}
+_SCOPES.update(dict.fromkeys(
+    ("featurize", "gather_features", "affine"), "repro.featurize"))
+_SCOPES.update(dict.fromkeys(
+    ("predict_model", "tree_gemm", "matmul_bias", "sigmoid", "relu",
+     "softmax", "argmax", "select_column", "threshold", "constant_vector"),
+    "repro.model"))
+
+
 def compile_plan(plan: Plan, catalog,
                  config: Optional[ExecutionConfig] = None,
                  capture: Optional[str] = None,
@@ -367,129 +379,133 @@ def compile_plan(plan: Plan, catalog,
             ins = [env[i] for i in n.inputs]
             a = n.attrs
             t0 = time.perf_counter() if node_hook is not None else 0.0
-            if op == "scan":
-                env[nid] = tables[a["table"]]
-            elif op == "materialized":
-                env[nid] = tables[a["slot"]]
-            elif op == "filter":
-                pred = a["predicate"]
-                if nid in parametric:
-                    pred = bound(pred)
-                env[nid] = rel_ops.filter_(ins[0], pred)
-            elif op == "project":
-                env[nid] = rel_ops.project(ins[0], a["columns"])
-            elif op == "rename":
-                t = ins[0]
-                mapping = a["mapping"]
-                cols = {mapping.get(k, k): v for k, v in t.columns.items()}
-                env[nid] = Table(cols, t.valid, t.schema.rename(mapping))
-            elif op == "map":
-                expr = a["expr"]
-                if nid in parametric:
-                    expr = bound(expr)
-                env[nid] = rel_ops.with_column(ins[0], a["name"], expr)
-            elif op == "join":
-                env[nid] = rel_ops.join_unique(ins[0], ins[1], on=a["on"],
-                                               how=a.get("how", "inner"))
-            elif op == "group_agg":
-                env[nid] = rel_ops.group_aggregate(
-                    ins[0], a["key"], a["aggs"], a.get("num_groups"))
-            elif op == "partial_agg":
-                # local phase of a two-phase aggregation: mergeable state
-                # per morsel; `serve/sharded.py` runs the combine stage
-                env[nid] = rel_ops.partial_aggregate(
-                    ins[0], a["key"], a["aggs"], a.get("num_groups"))
-            elif op == "order_by":
-                env[nid] = rel_ops.order_by(ins[0], a["key"],
-                                            a.get("descending", False))
-            elif op == "limit":
-                env[nid] = rel_ops.limit(ins[0], a["n"])
-            elif op == "union":
-                env[nid] = rel_ops.union_all(ins[0], ins[1])
-            elif op == "attach_column":
-                t, vec = ins
-                if vec.ndim == 2:
-                    vec = vec[:, 0]
-                env[nid] = t.with_columns({a["name"]: vec})
-            elif op == "featurize":
-                table = ins[0]
-                feats = [f.transform(table.columns) for f in a["featurizers"]]
-                env[nid] = jnp.concatenate(feats, axis=1)
-            elif op == "gather_features":
-                env[nid] = ins[0][:, jnp.asarray(a["indices"])]
-            elif op == "predict_model":
-                x = ins[0]
-                task = a.get("task", "classification")
-                proba = a.get("proba", False)
-                if n.runtime == "native":
-                    scores = _model_scores(a["model"], x)
-                    env[nid] = _scores_to_output(scores, task, proba)
-                elif n.runtime == "external":
-                    env[nid] = _external_predict(
-                        a["model"], task, proba,
-                        config.external_latency_s)(x)
-                else:  # container
-                    env[nid] = _external_predict(
-                        a["model"], task, proba,
-                        config.container_latency_s)(x)
-            # ---- LA ops produced by NN-translation / pruning rules ----------
-            elif op == "affine":
-                env[nid] = ins[0] * jnp.asarray(a["scale"]) \
-                    + jnp.asarray(a["offset"])
-            elif op == "matmul_bias":
-                env[nid] = jnp.dot(ins[0], jnp.asarray(a["weights"]),
-                                   precision=jax.lax.Precision.HIGHEST) \
-                    + jnp.asarray(a["bias"])
-            elif op == "sigmoid":
-                env[nid] = jax.nn.sigmoid(ins[0])
-            elif op == "relu":
-                env[nid] = jax.nn.relu(ins[0])
-            elif op == "softmax":
-                env[nid] = jax.nn.softmax(ins[0], axis=-1)
-            elif op == "argmax":
-                env[nid] = jnp.argmax(ins[0], axis=-1).astype(jnp.float32)
-            elif op == "select_column":
-                env[nid] = ins[0][:, a["index"]]
-            elif op == "threshold":
-                env[nid] = (ins[0] > a["value"]).astype(jnp.float32)
-            elif op == "tree_gemm":
-                ens = a["ensemble"]
-                # Strategy chosen by the cost-model crossover at plan time
-                # (nn_translation); ``use_pallas_tree_gemm`` force-overrides
-                # for benchmarks/back-compat.  The strategy attr participates
-                # in the plan signature, so differently-lowered plans never
-                # share a cached executable.
-                strategy = a.get("strategy", "gemm")
-                if config.use_pallas_tree_gemm or strategy == "pallas":
-                    from ..kernels.tree_gemm import ops as tg_ops
-                    scores = tg_ops.tree_gemm(ens, ins[0])
+            with jax.named_scope(_SCOPES.get(op, "repro.relational")):
+                if op == "scan":
+                    env[nid] = tables[a["table"]]
+                elif op == "materialized":
+                    env[nid] = tables[a["slot"]]
+                elif op == "filter":
+                    pred = a["predicate"]
+                    if nid in parametric:
+                        pred = bound(pred)
+                    env[nid] = rel_ops.filter_(ins[0], pred)
+                elif op == "project":
+                    env[nid] = rel_ops.project(ins[0], a["columns"])
+                elif op == "rename":
+                    t = ins[0]
+                    mapping = a["mapping"]
+                    cols = {mapping.get(k, k): v for k, v in t.columns.items()}
+                    env[nid] = Table(cols, t.valid, t.schema.rename(mapping))
+                elif op == "map":
+                    expr = a["expr"]
+                    if nid in parametric:
+                        expr = bound(expr)
+                    env[nid] = rel_ops.with_column(ins[0], a["name"], expr)
+                elif op == "join":
+                    env[nid] = rel_ops.join_unique(ins[0], ins[1], on=a["on"],
+                                                   how=a.get("how", "inner"))
+                elif op == "group_agg":
+                    env[nid] = rel_ops.group_aggregate(
+                        ins[0], a["key"], a["aggs"], a.get("num_groups"))
+                elif op == "partial_agg":
+                    # local phase of a two-phase aggregation: mergeable state
+                    # per morsel; `serve/sharded.py` runs the combine stage
+                    env[nid] = rel_ops.partial_aggregate(
+                        ins[0], a["key"], a["aggs"], a.get("num_groups"))
+                elif op == "order_by":
+                    env[nid] = rel_ops.order_by(ins[0], a["key"],
+                                                a.get("descending", False))
+                elif op == "limit":
+                    env[nid] = rel_ops.limit(ins[0], a["n"])
+                elif op == "union":
+                    env[nid] = rel_ops.union_all(ins[0], ins[1])
+                elif op == "attach_column":
+                    t, vec = ins
+                    if vec.ndim == 2:
+                        vec = vec[:, 0]
+                    env[nid] = t.with_columns({a["name"]: vec})
+                elif op == "featurize":
+                    table = ins[0]
+                    feats = [f.transform(table.columns)
+                             for f in a["featurizers"]]
+                    env[nid] = jnp.concatenate(feats, axis=1)
+                elif op == "gather_features":
+                    env[nid] = ins[0][:, jnp.asarray(a["indices"])]
+                elif op == "predict_model":
+                    x = ins[0]
+                    task = a.get("task", "classification")
+                    proba = a.get("proba", False)
+                    if n.runtime == "native":
+                        scores = _model_scores(a["model"], x)
+                        env[nid] = _scores_to_output(scores, task, proba)
+                    elif n.runtime == "external":
+                        env[nid] = _external_predict(
+                            a["model"], task, proba,
+                            config.external_latency_s)(x)
+                    else:  # container
+                        env[nid] = _external_predict(
+                            a["model"], task, proba,
+                            config.container_latency_s)(x)
+                # ---- LA ops produced by NN-translation / pruning rules ------
+                elif op == "affine":
+                    env[nid] = ins[0] * jnp.asarray(a["scale"]) \
+                        + jnp.asarray(a["offset"])
+                elif op == "matmul_bias":
+                    env[nid] = jnp.dot(ins[0], jnp.asarray(a["weights"]),
+                                       precision=jax.lax.Precision.HIGHEST) \
+                        + jnp.asarray(a["bias"])
+                elif op == "sigmoid":
+                    env[nid] = jax.nn.sigmoid(ins[0])
+                elif op == "relu":
+                    env[nid] = jax.nn.relu(ins[0])
+                elif op == "softmax":
+                    env[nid] = jax.nn.softmax(ins[0], axis=-1)
+                elif op == "argmax":
+                    env[nid] = jnp.argmax(ins[0], axis=-1).astype(jnp.float32)
+                elif op == "select_column":
+                    env[nid] = ins[0][:, a["index"]]
+                elif op == "threshold":
+                    env[nid] = (ins[0] > a["value"]).astype(jnp.float32)
+                elif op == "tree_gemm":
+                    ens = a["ensemble"]
+                    # Strategy chosen by the cost-model crossover at plan
+                    # time (nn_translation); ``use_pallas_tree_gemm``
+                    # force-overrides for benchmarks/back-compat.  The
+                    # strategy attr participates in the plan signature, so
+                    # differently-lowered plans never share a cached
+                    # executable.
+                    strategy = a.get("strategy", "gemm")
+                    if config.use_pallas_tree_gemm or strategy == "pallas":
+                        from ..kernels.tree_gemm import ops as tg_ops
+                        scores = tg_ops.tree_gemm(ens, ins[0])
+                    else:
+                        from ..ml.hummingbird import predict_ensemble_gemm
+                        scores = predict_ensemble_gemm(ens, ins[0])
+                    scores = scores + a.get("bias", 0.0)
+                    env[nid] = _scores_to_output(
+                        scores, a.get("task", "classification"),
+                        a.get("proba", False))
+                elif op == "constant_vector":
+                    n_rows = ins[0].shape[0] \
+                        if ins and hasattr(ins[0], "shape") \
+                        else ins[0].capacity
+                    env[nid] = jnp.full((n_rows,), a["value"], jnp.float32)
+                elif op == "udf":
+                    fn = a["fn"]
+                    out_dtype = a.get("dtype", jnp.float32)
+                    x = ins[0]
+                    rows = x.shape[0] if hasattr(x, "shape") else x.capacity
+                    shape = jax.ShapeDtypeStruct((rows,), out_dtype)
+                    if hasattr(x, "columns"):   # table input: pass column dict
+                        cols = {k: v for k, v in x.columns.items()}
+                        env[nid] = jax.pure_callback(
+                            lambda **kw: np.asarray(fn(kw), out_dtype), shape,
+                            **cols)
+                    else:
+                        env[nid] = jax.pure_callback(
+                            lambda v: np.asarray(fn(v), out_dtype), shape, x)
                 else:
-                    from ..ml.hummingbird import predict_ensemble_gemm
-                    scores = predict_ensemble_gemm(ens, ins[0])
-                scores = scores + a.get("bias", 0.0)
-                env[nid] = _scores_to_output(
-                    scores, a.get("task", "classification"),
-                    a.get("proba", False))
-            elif op == "constant_vector":
-                n_rows = ins[0].shape[0] if ins and hasattr(ins[0], "shape") \
-                    else ins[0].capacity
-                env[nid] = jnp.full((n_rows,), a["value"], jnp.float32)
-            elif op == "udf":
-                fn = a["fn"]
-                out_dtype = a.get("dtype", jnp.float32)
-                x = ins[0]
-                rows = x.shape[0] if hasattr(x, "shape") else x.capacity
-                shape = jax.ShapeDtypeStruct((rows,), out_dtype)
-                if hasattr(x, "columns"):   # table input: pass column dict
-                    cols = {k: v for k, v in x.columns.items()}
-                    env[nid] = jax.pure_callback(
-                        lambda **kw: np.asarray(fn(kw), out_dtype), shape,
-                        **cols)
-                else:
-                    env[nid] = jax.pure_callback(
-                        lambda v: np.asarray(fn(v), out_dtype), shape, x)
-            else:
-                raise ValueError(f"codegen: unknown op {op}")
+                    raise ValueError(f"codegen: unknown op {op}")
             if node_hook is not None:
                 env[nid] = jax.block_until_ready(env[nid])
                 node_hook(nid, n, env[nid], time.perf_counter() - t0)

@@ -21,7 +21,7 @@ from .sampling import sample_token
 from .sharded import (Morsel, ShardedExecutor, ShardPlacement, plan_morsels,
                       side_bucket_rows)
 from .telemetry import (NULL_TRACE, MetricsRegistry, Span, Trace,
-                        chrome_trace)
+                        profile_span)
 
 __all__ = ["InferenceEngine", "Request", "ServeConfig", "sample_token",
            "PredictionService", "PredictionTicket", "CompiledPrediction",
@@ -35,4 +35,4 @@ __all__ = ["InferenceEngine", "Request", "ServeConfig", "sample_token",
            "hash_buckets", "plan_exchange",
            "RequestContext", "Session", "TenantPolicy", "TenantStats",
            "ExplainResult", "MetricsRegistry", "NULL_TRACE", "Span", "Trace",
-           "chrome_trace"]
+           "profile_span"]

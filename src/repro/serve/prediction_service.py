@@ -124,8 +124,8 @@ from .admission import (AdmissionConfig, AdmissionLoop, AdmissionQueueFull,
 from .cache import CostAwareCache, value_nbytes
 from .context import RequestContext, Session, TenantPolicy
 from .sharded import ShardedExecutor, side_bucket_rows
-from .telemetry import (MetricsRegistry, NULL_TRACE, Trace, chrome_trace,
-                        next_trace_id)
+from .telemetry import (MetricsRegistry, NULL_TRACE, NullTrace, Trace,
+                        next_trace_id, profile_span)
 
 __all__ = ["PredictionService", "ServiceStats", "PredictionTicket",
            "CompiledPrediction", "DistributedSpec", "AggStage",
@@ -164,6 +164,10 @@ class ServiceStats:
     batch_executions: int = 0       # actual executions issued to the engine
     coalesced_requests: int = 0     # requests served without their own execution
     chunks_executed: int = 0
+    launches: int = 0               # device programs the executor issued:
+                                    # compiled-program calls plus the
+                                    # morsel loop's eager slices, pads,
+                                    # concats and trims
     # result-cache tier
     result_hits: int = 0            # spliced executions served from cache
     result_misses: int = 0          # spliced executions that re-materialized
@@ -493,6 +497,15 @@ def _stack_pad_host(tables: List[Table], target: int) -> Table:
     if pad:
         valid = np.pad(valid, (0, pad))
     return Table(cols, jnp.asarray(valid), base.schema)
+
+
+def _n_arrays(value: Any) -> int:
+    """Device arrays in a table (its columns and validity mask) or a
+    matrix: the eager ops that one slice, pad, concat or trim of it
+    dispatches."""
+    if isinstance(value, Table):
+        return len(value.columns) + 1
+    return 1
 
 
 def _rows_of(out: Any) -> int:
@@ -1003,7 +1016,7 @@ class PredictionService:
     def _new_trace(self, name: str,
                    ctx: Optional[RequestContext]) -> Any:
         if not self.telemetry:
-            return NULL_TRACE
+            return NullTrace(next_trace_id())
         attrs = {}
         if ctx is not None:
             if ctx.tenant:
@@ -1014,7 +1027,7 @@ class PredictionService:
 
     def _finish_trace(self, trace: Any) -> None:
         """Seal a request's trace and retain it in the last-N ring (the
-        export buffer behind :meth:`traces` / :meth:`export_traces`)."""
+        buffer behind :meth:`traces`)."""
         if trace is None or not trace.enabled \
                 or trace.finished is not None:
             return                     # already sealed (idempotent)
@@ -1026,12 +1039,6 @@ class PredictionService:
         oldest first."""
         out = list(self._traces)
         return out if n is None else out[-n:]
-
-    def export_traces(self, path: Optional[str] = None) -> Dict[str, Any]:
-        """Retained traces as a Chrome-trace/Perfetto JSON object (written
-        to ``path`` when given — load it in ``chrome://tracing`` or
-        https://ui.perfetto.dev)."""
-        return chrome_trace(self.traces(), path=path)
 
     def metrics_snapshot(self) -> Dict[str, Any]:
         """Point-in-time view of every counter/gauge/histogram (hot-path
@@ -1416,6 +1423,7 @@ class PredictionService:
                            producer=("rematerialized", ref.sig))
         with self._lock:
             self.stats.rematerializations += 1
+            self.stats.launches += 1
         return value
 
     def _subtree_raw_fn(self, ref: SubplanRef) -> Any:
@@ -1455,11 +1463,14 @@ class PredictionService:
         (key computation hashes the whole plan — not free on the warm
         path).  ``ctx`` informs the append-upgrade decision only (whether
         a freshness SLA could recover a non-row-local subtree)."""
-        plan = self._to_plan(query)
-        key, sig = _key if _key is not None \
-            else self._cache_key(plan, tables)
-        hit = self._exec_cache.get(key)
-        if hit is not None:
+        with profile_span("compile", trace.trace_id) as span:
+            plan = self._to_plan(query)
+            key, sig = _key if _key is not None \
+                else self._cache_key(plan, tables)
+            hit = self._exec_cache.get(key)
+            span.set_metadata(hit=hit is not None)
+            if hit is None:
+                return self._compile_miss(plan, key, sig, tables, trace)
             with self._lock:
                 self.stats.cache_hits += 1
             trace.event("executable_cache", result="hit")
@@ -1467,6 +1478,12 @@ class PredictionService:
             if upgraded is None:
                 upgraded = self._maybe_append_upgrade(key, hit, ctx)
             return upgraded if upgraded is not None else hit
+
+    def _compile_miss(self, plan: Plan, key: Tuple, sig: str,
+                      tables: Optional[Dict[str, Table]],
+                      trace: Any) -> CompiledPrediction:
+        """Optimize, codegen and jit a plan the executable cache missed,
+        and cache it."""
         with self._lock:
             self.stats.cache_misses += 1
         trace.event("executable_cache", result="miss")
@@ -1954,22 +1971,27 @@ class PredictionService:
                                         tenant=tenant, trace=trace)
         else:
             out = self._execute_whole(compiled, tabs, store_capture,
-                                      tenant=tenant)
+                                      tenant=tenant, trace=trace)
         # A served result is a *ready* result: external/container plans run
         # host callbacks under async dispatch, and letting those trail the
         # ticket resolution deadlocks against the caller's next dispatch.
-        return jax.block_until_ready(out)
+        with profile_span("device_wait", trace.trace_id):
+            return jax.block_until_ready(out)
 
     def _execute_whole(self, compiled: CompiledPrediction,
                        tabs: Dict[str, Table],
                        store_capture: bool = True,
-                       tenant: Optional[str] = None) -> Any:
+                       tenant: Optional[str] = None,
+                       trace: Any = NULL_TRACE) -> Any:
         """One whole-input execution of the fused program (the base tier;
         also the fallback when a sharded execution loses its partitioning
         mid-flight)."""
         t0 = time.perf_counter()
         raw = compiled.fn(tabs)
-        raw = jax.block_until_ready(raw)
+        with self._lock:
+            self.stats.launches += 1
+        with profile_span("device_wait", trace.trace_id):
+            raw = jax.block_until_ready(raw)
         if compiled.capture is None:
             return raw
         out, captured = raw
@@ -2041,7 +2063,7 @@ class PredictionService:
             # partitioning vanished between _should_shard and here (the
             # table was re-registered unpartitioned): serve whole-table
             return self._execute_whole(compiled, tabs, store_capture,
-                                       tenant=tenant)
+                                       tenant=tenant, trace=trace)
         executor = self._shard_executor()
         scan = next(n for n in compiled.plan.nodes.values()
                     if n.op == "scan")
@@ -2076,6 +2098,7 @@ class PredictionService:
         self._record_twin_cost(twin, fresh, tags, elapsed)
         with self._lock:
             self.stats.sharded_executions += 1
+            self.stats.launches += max(placement.n_morsels, 1)
             self.stats.shard_waves += placement.n_waves
             self.stats.partitions_scanned += len(parts)
             self.stats.partitions_pruned += pt.n_partitions - len(parts)
@@ -2329,6 +2352,7 @@ class PredictionService:
         self._record_twin_cost(twin, fresh, tags,
                                time.perf_counter() - t0)
         with self._lock:
+            self.stats.launches += max(placement.n_morsels, 1)
             self.stats.shard_waves += placement.n_waves
             self.stats.partitions_scanned += len(parts)
             self.stats.partitions_pruned += \
@@ -2391,7 +2415,7 @@ class PredictionService:
                 return False, None, 0
             placement = plan_exchange(a_cols[exch.on], s_cols[exch.on],
                                       n_buckets, cfg.shard_min_bucket_rows)
-            if sp is not None:
+            if trace.enabled:
                 sp.attrs.update(placement.describe())
         twin, fresh, tags = self._twin_executable(
             compiled,
@@ -2421,6 +2445,7 @@ class PredictionService:
         moved = placement.bytes_moved(row_bytes(a_cols), row_bytes(s_cols))
         with self._lock:
             self.stats.exchange_executions += 1
+            self.stats.launches += max(len(placement.active_buckets), 1)
             self.stats.exchange_bytes_moved += moved
             self.stats.shard_waves += placement.n_waves(executor.n_devices)
             self.stats.partitions_scanned += a_used + s_used
@@ -2495,6 +2520,8 @@ class PredictionService:
             # very path the delta tier exists to keep compile-free.
             if from_prefix and compiled.raw_fn is not None:
                 return compiled.raw_fn({**tabs, ref.slot: value})
+            with self._lock:
+                self.stats.launches += 1
             return compiled.fn({**tabs, ref.slot: value})
 
     def _serve_from_prefix(self, compiled: CompiledPrediction,
@@ -2595,6 +2622,7 @@ class PredictionService:
         with self._lock:
             self.stats.delta_serves += 1
             self.stats.delta_rows_scanned += d
+            self.stats.launches += 1
         return value
 
     def _execute_chunked(self, compiled: CompiledPrediction,
@@ -2608,29 +2636,46 @@ class PredictionService:
         name = compiled.morsel_table
         table = tabs[name]
         n = table.capacity
+        tid = trace.trace_id
         trace.event("chunked", rows=n, chunk_rows=self.chunk_rows)
         pieces, captured = [], []
+        # one eager op per column and validity mask for each slice, tail
+        # pad, concat and trim (a trim to the whole length is free); one
+        # per program call
+        launches = 0
+        assembly = 2 if n % self.chunk_rows else 1
         t0 = time.perf_counter()
         for start in range(0, n, self.chunk_rows):
-            chunk = _slice_table(table, start, self.chunk_rows)
-            raw = compiled.fn({**tabs, name: chunk})
+            with profile_span("morsel.slice", tid):
+                chunk = _slice_table(table, start, self.chunk_rows)
+            launches += _n_arrays(table) \
+                * (1 if start + self.chunk_rows <= n else 2)
+            with profile_span("morsel.launch", tid):
+                raw = compiled.fn({**tabs, name: chunk})
+            launches += 1
             if compiled.capture is not None:
                 pieces.append(raw[0])
                 captured.append(raw[1])
             else:
                 pieces.append(raw)
-            with self._lock:
-                self.stats.chunks_executed += 1
         if compiled.capture is not None and captured and store_capture:
             # morsel_table holds for the capture root too, so its rows are
             # the morsel's rows and the pieces concatenate to exactly the
             # whole-table subtree value
-            cap = jax.block_until_ready(
-                _trim_rows(_concat_outputs(captured), n))
+            with profile_span("assemble", tid):
+                cap = jax.block_until_ready(
+                    _trim_rows(_concat_outputs(captured), n))
+            launches += assembly * _n_arrays(cap)
             self._store_result(compiled.capture, cap,
                                time.perf_counter() - t0,
                                producer=compiled.key, tenant=tenant)
-        return _trim_rows(_concat_outputs(pieces), n)
+        with profile_span("assemble", tid):
+            out = _trim_rows(_concat_outputs(pieces), n)
+        launches += assembly * _n_arrays(out)
+        with self._lock:
+            self.stats.chunks_executed += len(pieces)
+            self.stats.launches += launches
+        return out
 
     def run(self, query: Union[str, Plan],
             tables: Optional[Dict[str, Table]] = None,
@@ -2768,10 +2813,11 @@ class PredictionService:
             # key[2] is the overridden-tables tuple: only override-table
             # requests stack (batch size matters); identical-catalog
             # groups share one execution and must never be split
-            self.batcher.offer(batch_key,
-                               _Pending(plan, tables, ticket,
-                                        params=bound, ctx=ctx, trace=trace),
-                               chunk=bool(key[2]), ctx=ctx)
+            with profile_span("admit", trace.trace_id):
+                self.batcher.offer(
+                    batch_key, _Pending(plan, tables, ticket, params=bound,
+                                        ctx=ctx, trace=trace),
+                    chunk=bool(key[2]), ctx=ctx)
         except AdmissionQueueFull:
             with self._lock:
                 self.stats.queue_rejections += 1
@@ -2796,16 +2842,29 @@ class PredictionService:
         reading — the deterministic seam the background loop and the fake-
         clock tests share.  ``force`` serves everything (explicit flush)."""
         served = 0
-        groups = self.batcher.drain() if force \
-            else self.batcher.pop_ready(self.clock.monotonic())
+        with profile_span("admit"):
+            groups = self.batcher.drain() if force \
+                else self.batcher.pop_ready(self.clock.monotonic())
         for group in groups:
             served += self._serve_ready(group)
         return served
 
     def _serve_ready(self, group: ReadyGroup) -> int:
-        """Account for one released group (flush reason + queue latency),
-        then serve it.  Called by the loop thread, ``flush()``, and
-        ``admission_tick``; ``_flush_lock`` serializes the execution."""
+        """Account for one released group, then serve it.  Called by the
+        loop thread, ``flush()``, and ``admission_tick``; ``_flush_lock``
+        serializes the execution."""
+        with profile_span("admit", group.items[0].trace.trace_id):
+            tenant = self._account_ready(group)
+        with self._flush_lock:
+            served = self._serve_group(group.key, group.items)
+        if tenant is not None and served:
+            with self._lock:
+                self._tenant_stat(tenant).served += served
+        return served
+
+    def _account_ready(self, group: ReadyGroup) -> Optional[str]:
+        """Flush reason and queue latency of a released group; returns its
+        tenant."""
         now = self.clock.monotonic()
         tenant = group.ctx.tenant if group.ctx is not None else None
         lats: List[float] = []
@@ -2844,12 +2903,7 @@ class PredictionService:
                 self.metrics.observe(
                     "repro_queue_wait_seconds", lat,
                     labels={"tenant": tenant} if tenant else None)
-        with self._flush_lock:
-            served = self._serve_group(group.key, group.items)
-        if tenant is not None and served:
-            with self._lock:
-                self._tenant_stat(tenant).served += served
-        return served
+        return tenant
 
     def _fail_group(self, group: ReadyGroup, err: BaseException) -> None:
         """Loop escape hatch: an error that got past ``_serve_group``'s own
@@ -3020,7 +3074,8 @@ class PredictionService:
             self.stats.evictions += len(evicted)
 
     def _execute_direct(self, compiled: CompiledPrediction,
-                        tabs: Dict[str, Table]) -> Any:
+                        tabs: Dict[str, Table],
+                        trace: Any = NULL_TRACE) -> Any:
         """Execute a shape-bucket executable on already-padded inputs: no
         chunk split (the bucket *is* the static shape) and no capture store
         (a padded stack is not the catalog data the result-cache key would
@@ -3028,10 +3083,12 @@ class PredictionService:
         compiled.serves += 1
         with self._lock:
             self.stats.batch_executions += 1
+            self.stats.launches += 1
         raw = compiled.fn(tabs)
         if compiled.capture is not None:
             raw = raw[0]
-        return jax.block_until_ready(raw)
+        with profile_span("device_wait", trace.trace_id):
+            return jax.block_until_ready(raw)
 
     def _serve_stacked(self, compiled: CompiledPrediction,
                        group: List[_Pending],
@@ -3072,7 +3129,7 @@ class PredictionService:
                 tabs["__params__"] = params
             t0 = time.perf_counter()
             with trace.span("execute", stacked=len(group), bucket=bucket):
-                out = self._execute_direct(bcompiled, tabs)
+                out = self._execute_direct(bcompiled, tabs, trace=trace)
             self._record_twin_cost(bcompiled, fresh, btags,
                                    time.perf_counter() - t0)
         # no device-side trim: the host-side split only reads rows up to

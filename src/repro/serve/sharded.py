@@ -74,6 +74,7 @@ from ..core.codegen import pow2_bucket
 from ..core.partition import Partition
 from ..distributed.sharding import data_axes_of
 from ..relational.table import Table
+from .telemetry import profile_span
 
 __all__ = ["Morsel", "ShardPlacement", "ShardedExecutor", "plan_morsels",
            "side_bucket_rows"]
@@ -247,13 +248,17 @@ class ShardedExecutor:
         the combined value is returned.
 
         ``trace`` (a :class:`~repro.serve.telemetry.Trace`, or ``None``)
-        records one ``shard_wave`` span per morsel on track ``device+1``
-        — worker threads genuinely overlap, so spans go through the
-        out-of-band ``add_span`` seam rather than the phase stack."""
+        records one ``shard_wave`` span per morsel — worker threads
+        genuinely overlap, so spans go through the out-of-band
+        ``add_span`` seam rather than the phase stack.  On the profiler's
+        timeline each morsel's ``repro.shard.prepare`` (caller thread),
+        ``repro.shard.run`` (its device's worker thread) and
+        ``repro.shard.split`` carry the request's trace id."""
         if capture and (combine is not None or unwrap is not None):
             raise ValueError("capture=True is row-local reassembly; it "
                              "composes with neither combine nor unwrap")
         part_map = {p.index: p for p in partitions}
+        tid = getattr(trace, "trace_id", 0)
         if hasattr(source, "host_view"):           # PartitionedTable
             host_cols, host_valid = source.host_view()
             table = source.table
@@ -290,16 +295,17 @@ class ShardedExecutor:
             inside the device workers makes the workers contend with each
             other instead of overlapping their (GIL-free) execution
             waits."""
-            parts = [part_map[i] for i in morsel.partitions]
-            tables = {scan_name: gather_pad(
-                host_cols, host_valid, parts, bucket - morsel.rows,
-                table.schema, device)}
-            for name, (s_cols, s_valid, s_parts, srows, s_schema) \
-                    in side_views.items():
-                aligned = [s_parts[i] for i in morsel.partitions]
-                rows = sum(p.n_rows for p in aligned)
-                tables[name] = gather_pad(s_cols, s_valid, aligned,
-                                          srows - rows, s_schema, device)
+            with profile_span("shard.prepare", tid):
+                parts = [part_map[i] for i in morsel.partitions]
+                tables = {scan_name: gather_pad(
+                    host_cols, host_valid, parts, bucket - morsel.rows,
+                    table.schema, device)}
+                for name, (s_cols, s_valid, s_parts, srows, s_schema) \
+                        in side_views.items():
+                    aligned = [s_parts[i] for i in morsel.partitions]
+                    rows = sum(p.n_rows for p in aligned)
+                    tables[name] = gather_pad(s_cols, s_valid, aligned,
+                                              srows - rows, s_schema, device)
             return tables
 
         def split_rows(raw: Any, parts: Sequence[Partition]) -> List[Any]:
@@ -338,9 +344,10 @@ class ShardedExecutor:
                 # partial-aggregate state: one mergeable value per morsel,
                 # ordered by its first partition for the combine fold
                 return [(parts[0].index, raw, None)]
-            outs = split_rows(raw, parts)
-            caps = (split_rows(jax.block_until_ready(cap), parts)
-                    if capture else [None] * len(parts))
+            with profile_span("shard.split", tid):
+                outs = split_rows(raw, parts)
+                caps = (split_rows(jax.block_until_ready(cap), parts)
+                        if capture else [None] * len(parts))
             return [(p.index, o, c) for p, o, c in zip(parts, outs, caps)]
 
         active = [d for d in range(self.n_devices)
@@ -354,12 +361,12 @@ class ShardedExecutor:
             pieces: List[Tuple[int, Any, Any]] = []
             for morsel, tables in prepared[d]:
                 t0 = trace.clock.monotonic() if live else 0.0
-                out = run_morsel(morsel, tables)
+                with profile_span("shard.run", tid):
+                    out = run_morsel(morsel, tables)
                 self.morsels_per_device[d] += 1
                 if live:
                     trace.add_span("shard_wave", t0,
-                                   trace.clock.monotonic(), tid=d + 1,
-                                   device=d,
+                                   trace.clock.monotonic(), device=d,
                                    partitions=len(morsel.partitions),
                                    rows=morsel.rows)
                 pieces.extend(out)
@@ -549,22 +556,24 @@ class ShardedExecutor:
             return np.asarray(raw)[:rows]
 
         live = trace is not None and getattr(trace, "enabled", False)
+        tid = getattr(trace, "trace_id", 0)
 
         def run_device(d: int) -> List[Tuple[int, Any, Any]]:
             pieces: List[Tuple[int, Any, Any]] = []
             for b, tables in prepared[d]:
                 t0 = trace.clock.monotonic() if live else 0.0
-                raw = fn(tables)
-                cap = None
-                if capture:
-                    raw, cap = raw
-                elif unwrap is not None:
-                    raw = unwrap(raw)
-                raw = jax.block_until_ready(raw)
+                with profile_span("exchange_bucket", tid):
+                    raw = fn(tables)
+                    cap = None
+                    if capture:
+                        raw, cap = raw
+                    elif unwrap is not None:
+                        raw = unwrap(raw)
+                    raw = jax.block_until_ready(raw)
                 if live:
                     trace.add_span(
                         "exchange_bucket", t0, trace.clock.monotonic(),
-                        tid=d + 1, device=d, bucket=b,
+                        device=d, bucket=b,
                         rows=len(placement.anchor_index[b]))
                 if combine is not None:
                     pieces.append((b, raw, None))
@@ -623,8 +632,10 @@ class ShardedExecutor:
                 return Table(cols, valid, schema)
             return jnp.asarray(np.concatenate(items, axis=0)[inv])
 
-        out = reassemble([p[1] for p in pieces])
-        cap_out = reassemble([p[2] for p in pieces]) if capture else None
+        with profile_span("exchange_scatter", tid):
+            out = reassemble([p[1] for p in pieces])
+            cap_out = reassemble([p[2] for p in pieces]) if capture \
+                else None
         if live:
             trace.add_span("exchange_scatter", t_scatter,
                            trace.clock.monotonic(),

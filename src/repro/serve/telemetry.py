@@ -1,7 +1,7 @@
 """Low-overhead request tracing + unified metrics registry.
 
-Two independent pieces, both designed so the *off* switch costs nothing
-on the hot path:
+Three pieces, each designed so the *off* switch costs nothing on the hot
+path:
 
 - ``Trace``/``Span``: a per-request span tree recorded against the
   service's injectable ``Clock`` (so ``ManualClock`` tests pin span
@@ -10,10 +10,20 @@ on the hot path:
   stack — request phases are sequential in time even when they hop
   threads: submit thread -> admission loop -> per-group serve); shard
   and exchange *worker* threads, which genuinely overlap, record
-  finished spans out-of-band with ``trace.add_span(...)`` carrying a
-  ``tid`` (device index).  ``NULL_TRACE`` is a shared no-op singleton:
-  with ``telemetry=False`` every span site touches one attribute and
-  one pre-built context manager, nothing else.
+  finished spans out-of-band with ``trace.add_span(...)``.
+  ``NULL_TRACE`` (and a per-request ``NullTrace`` carrying only a trace
+  id) is the ``telemetry=False`` hot path: no span objects, no clock
+  reads.
+
+- Profiler spans: ``profile_span(name, trace_id)`` opens a
+  ``jax.profiler.TraceAnnotation`` named ``repro.<name>`` on the calling
+  thread, with the request's ``trace_id`` as its argument.  Every
+  ``trace.span(...)`` goes through it, as do the executor's own spans
+  (``admit``, ``compile``, ``morsel.slice``, ``morsel.launch``,
+  ``assemble``, ``device_wait``, ``shard.*``), whatever ``telemetry`` is
+  set to: they land on the profiler's host timeline beside the device
+  ops whenever a profiler session runs (``jax.profiler.trace``), and
+  cost one annotation object when none does.
 
 - ``MetricsRegistry``: counters, gauges and fixed-bucket histograms
   keyed by ``(name, labels)``, with pull-time *collectors* (the service
@@ -23,21 +33,18 @@ on the hot path:
   Prometheus text-format ``render()``.  ``writes`` counts hot-path
   mutations — the telemetry-off tests assert it stays zero while the
   collector-backed gauges keep working (collection is a read).
-
-Chrome-trace export: ``chrome_trace(traces)`` returns the
-``{"traceEvents": [...]}`` JSON object loadable in Perfetto /
-``chrome://tracing`` ("X" complete events, microsecond timestamps).
 """
 
 from __future__ import annotations
 
 import itertools
-import json
 import threading
 from typing import Any, Callable, Dict, Iterator, List, Optional, Tuple
 
-__all__ = ["Span", "Trace", "NULL_TRACE", "MetricsRegistry",
-           "DEFAULT_LATENCY_BUCKETS", "chrome_trace"]
+from jax.profiler import TraceAnnotation
+
+__all__ = ["Span", "Trace", "NULL_TRACE", "NullTrace", "MetricsRegistry",
+           "DEFAULT_LATENCY_BUCKETS", "profile_span"]
 
 # Latency histogram buckets (seconds): 100us .. 10s, roughly log-spaced.
 # Fixed so series are comparable across processes and PRs.
@@ -46,21 +53,27 @@ DEFAULT_LATENCY_BUCKETS: Tuple[float, ...] = (
     0.1, 0.25, 0.5, 1.0, 2.5, 5.0, 10.0)
 
 
+def profile_span(name: str, trace_id: int = 0) -> TraceAnnotation:
+    """A ``repro.<name>`` span on the profiler's host timeline, on the
+    calling thread, carrying the request's ``trace_id``.  With no profiler
+    session running it costs one annotation object: no lock, no clock
+    read, no registry write."""
+    return TraceAnnotation("repro." + name, trace_id=trace_id)
+
+
 class Span:
     """One timed phase of a request.  ``start``/``end`` are clock-domain
-    seconds (the service's injected ``Clock``); ``tid`` groups spans into
-    Chrome-trace tracks (0 = the request's own track, 1+N = device N)."""
+    seconds (the service's injected ``Clock``)."""
 
-    __slots__ = ("name", "start", "end", "attrs", "children", "tid")
+    __slots__ = ("name", "start", "end", "attrs", "children")
 
-    def __init__(self, name: str, start: float, tid: int = 0,
+    def __init__(self, name: str, start: float,
                  attrs: Optional[Dict[str, Any]] = None):
         self.name = name
         self.start = start
         self.end: Optional[float] = None
         self.attrs: Dict[str, Any] = attrs or {}
         self.children: List["Span"] = []
-        self.tid = tid
 
     @property
     def duration(self) -> float:
@@ -77,17 +90,20 @@ class Span:
 
 
 class _SpanCtx:
-    __slots__ = ("_trace", "_span")
+    __slots__ = ("_trace", "_span", "_profile")
 
     def __init__(self, trace: "Trace", span: Span):
         self._trace = trace
         self._span = span
+        self._profile = profile_span(span.name, trace.trace_id)
 
     def __enter__(self) -> Span:
+        self._profile.__enter__()
         return self._span
 
     def __exit__(self, exc_type, exc, tb):
         self._trace._close(self._span, failed=exc_type is not None)
+        self._profile.__exit__(exc_type, exc, tb)
         return False
 
 
@@ -130,11 +146,13 @@ class Trace:
             while self._stack and self._stack.pop() is not span:
                 pass
 
-    def add_span(self, name: str, start: float, end: float, tid: int = 0,
+    def add_span(self, name: str, start: float, end: float,
                  **attrs) -> Span:
         """Record an already-timed span (worker threads: shard waves,
-        exchange buckets).  Parents under the currently open phase span."""
-        s = Span(name, start, tid=tid, attrs=attrs)
+        exchange buckets).  Parents under the currently open phase span.
+        It stays in this tree: the worker's own ``profile_span`` is what
+        reaches the profiler."""
+        s = Span(name, start, attrs=attrs)
         s.end = end
         with self._lock:
             parent = self._stack[-1] if self._stack else None
@@ -187,50 +205,28 @@ class Trace:
             fmt(r, 1)
         return "\n".join(lines)
 
-    def to_chrome_events(self, pid: int = 0) -> List[Dict[str, Any]]:
-        """Chrome-trace "X" (complete) events, microsecond clock domain."""
-        events: List[Dict[str, Any]] = []
-        for s in self.spans():
-            events.append({
-                "name": s.name, "ph": "X", "pid": pid,
-                "tid": s.tid,
-                "ts": round(s.start * 1e6, 3),
-                "dur": round(max(0.0, s.duration) * 1e6, 3),
-                "args": {k: (v if isinstance(v, (int, float, str, bool))
-                             or v is None else repr(v))
-                         for k, v in s.attrs.items()},
-            })
-        return events
 
+class NullTrace:
+    """The ``telemetry=off`` hot path: records nothing, and carries only
+    the request's ``trace_id`` for its profiler spans (``NULL_TRACE``, id
+    0, where there is no request)."""
 
-class _NullCtx:
-    __slots__ = ()
-
-    def __enter__(self):
-        return None
-
-    def __exit__(self, exc_type, exc, tb):
-        return False
-
-
-_NULL_CTX = _NullCtx()
-
-
-class _NullTrace:
-    """Shared do-nothing trace: the ``telemetry=off`` hot path."""
+    __slots__ = ("trace_id",)
 
     enabled = False
-    trace_id = 0
     name = "null"
     attrs: Dict[str, Any] = {}
     roots: List[Span] = []
     started = 0.0
     finished: Optional[float] = 0.0
 
-    def span(self, name: str, **attrs) -> _NullCtx:
-        return _NULL_CTX
+    def __init__(self, trace_id: int = 0):
+        self.trace_id = trace_id
 
-    def add_span(self, name: str, start: float, end: float, tid: int = 0,
+    def span(self, name: str, **attrs) -> TraceAnnotation:
+        return profile_span(name, self.trace_id)
+
+    def add_span(self, name: str, start: float, end: float,
                  **attrs) -> None:
         return None
 
@@ -256,28 +252,8 @@ class _NullTrace:
     def pretty(self) -> str:
         return "trace disabled"
 
-    def to_chrome_events(self, pid: int = 0) -> List[Dict[str, Any]]:
-        return []
 
-
-NULL_TRACE = _NullTrace()
-
-
-def chrome_trace(traces, path: Optional[str] = None) -> Dict[str, Any]:
-    """Fold traces into one Chrome-trace/Perfetto JSON object (each trace
-    becomes a ``pid`` with its spans as complete events).  Optionally
-    writes it to ``path``."""
-    events: List[Dict[str, Any]] = []
-    for i, t in enumerate(traces):
-        pid = t.trace_id or i
-        events.append({"name": "process_name", "ph": "M", "pid": pid,
-                       "args": {"name": f"{t.name} #{t.trace_id}"}})
-        events.extend(t.to_chrome_events(pid=pid))
-    doc = {"traceEvents": events, "displayTimeUnit": "ms"}
-    if path is not None:
-        with open(path, "w") as fh:
-            json.dump(doc, fh, indent=1)
-    return doc
+NULL_TRACE = NullTrace()
 
 
 # ---------------------------------------------------------------------------

@@ -3,8 +3,8 @@
 Four layers of guarantees:
 
 1. **Span mechanics are exact** (ManualClock, no threads): durations,
-   nesting, worker ``add_span`` tracks, events, and the Chrome-trace
-   export shape are pinned to deterministic clock readings.
+   nesting, worker ``add_span`` records and events are pinned to
+   deterministic clock readings.
 2. **MetricsRegistry semantics**: counter/gauge/histogram keying by
    ``(name, labels)``, pull-time collectors sampled at read time, and
    Prometheus text rendering (TYPE lines, cumulative ``le`` buckets).
@@ -12,9 +12,14 @@ Four layers of guarantees:
    coalesced groups, result-cache splice, sharded morsels, and the
    shuffle exchange each leave their signature spans in the request's
    trace — the observability contract the EXPLAIN/trace tooling reads.
-4. **Off is free**: ``telemetry=False`` yields the shared NULL_TRACE
-   (zero spans retained, ``ticket.trace()`` is None) and zero hot-path
-   registry writes, while pull-time collectors keep working.
+4. **Off is free**: ``telemetry=False`` yields a null trace (zero spans
+   retained, ``ticket.trace()`` is None) and zero hot-path registry
+   writes, while pull-time collectors keep working.
+5. **Profiler spans**: under a profiler session the same spans, and the
+   executor's own, reach the profiler's host timeline as ``repro.*``
+   annotations carrying the request's trace id, with telemetry on or
+   off; the device ops carry ``repro.<layer>`` name scopes; and
+   ``ServiceStats.launches`` counts the device programs issued.
 
 Plus the operator-level EXPLAIN ANALYZE contract: on an external-model
 shuffle-join query (known per-operator latency floor) the per-operator
@@ -22,8 +27,11 @@ measured times must sum to within 20% of the measured end-to-end wall
 time.
 """
 
-import json
+import glob
+import os
+from collections import Counter
 
+import jax
 import numpy as np
 import pytest
 
@@ -31,11 +39,10 @@ from repro.core import ExecutionConfig, ModelStore, OptimizerConfig
 from repro.core.ir import Plan
 from repro.data import hospital_tables
 from repro.ml import (DecisionTree, LogisticRegression, Pipeline,
-                      PipelineMetadata, StandardScaler)
+                      PipelineMetadata, RandomForest, StandardScaler)
 from repro.relational.table import Table
 from repro.serve import (NULL_TRACE, AdmissionConfig, ManualClock,
-                         MetricsRegistry, PredictionService, Trace,
-                         chrome_trace)
+                         MetricsRegistry, PredictionService, Span, Trace)
 
 pytestmark = pytest.mark.tier1
 
@@ -104,41 +111,28 @@ def test_worker_add_span_and_events():
     tr = Trace(clock)
     tr.event("cache", result="hit")
     with tr.span("execute"):
-        # overlapping worker spans, recorded out-of-band with device tids
-        tr.add_span("shard_wave", 0.0, 0.5, tid=1, device=0)
-        tr.add_span("shard_wave", 0.0, 0.75, tid=2, device=1)
+        # overlapping worker spans, recorded out-of-band per device
+        tr.add_span("shard_wave", 0.0, 0.5, device=0)
+        tr.add_span("shard_wave", 0.0, 0.75, device=1)
         clock.advance(0.75)
     ev = tr.find("cache")
     assert ev.duration == 0.0 and ev.attrs == {"result": "hit"}
     waves = [s for s in tr.spans() if s.name == "shard_wave"]
-    assert [w.tid for w in waves] == [1, 2]
+    assert [w.attrs["device"] for w in waves] == [0, 1]
+    assert [w.duration for w in waves] == [0.5, 0.75]
     # workers parent under the phase span that was open when they recorded
     assert all(w in tr.find("execute").children for w in waves)
 
 
-def test_chrome_trace_export_shape(tmp_path):
-    clock = ManualClock()
-    tr = Trace(clock, trace_id=3, name="q1")
-    with tr.span("execute", rows=4):
-        clock.advance(0.5)
-    tr.finish()
-    path = tmp_path / "trace.json"
-    doc = chrome_trace([tr], path=str(path))
-    assert doc == json.loads(path.read_text())
-    meta, span = doc["traceEvents"]
-    assert meta["ph"] == "M" and meta["args"]["name"] == "q1 #3"
-    assert span["ph"] == "X" and span["name"] == "execute"
-    assert span["dur"] == 0.5e6 and span["args"] == {"rows": 4}
-
-
 def test_null_trace_is_inert():
     with NULL_TRACE.span("anything", x=1) as s:
-        assert s is None
+        # the profiler annotation alone: no Span is recorded
+        assert not isinstance(s, Span)
     assert NULL_TRACE.event("e") is None
     assert NULL_TRACE.add_span("w", 0.0, 1.0) is None
     assert not NULL_TRACE.enabled
     assert NULL_TRACE.span_names() == []
-    assert NULL_TRACE.to_chrome_events() == []
+    assert list(NULL_TRACE.spans()) == []
 
 
 # ---------------------------------------------------------------------------
@@ -267,7 +261,8 @@ def test_sharded_trace_carries_shard_waves():
     assert svc.stats.sharded_executions == 1
     (tr,) = svc.traces()
     waves = [s for s in tr.spans() if s.name == "shard_wave"]
-    assert waves and all(w.tid >= 1 for w in waves)
+    n_dev = svc.shard_info()["devices"]
+    assert waves and all(0 <= w.attrs["device"] < n_dev for w in waves)
     assert sum(w.attrs["partitions"] for w in waves) \
         == svc.stats.partitions_scanned
     svc.close()
@@ -316,20 +311,10 @@ def test_exchange_trace_spans_and_placement_attrs():
     assert build.attrs["n_buckets"] >= 1          # ExchangePlacement.describe
     assert build.attrs["anchor_rows_total"] == 192
     buckets = [s for s in tr.spans() if s.name == "exchange_bucket"]
-    assert buckets and all(b.tid >= 1 for b in buckets)
+    n_dev = svc.shard_info()["devices"]
+    assert buckets and all(0 <= b.attrs["device"] < n_dev for b in buckets)
     scatter = tr.find("exchange_scatter")
     assert scatter is not None and scatter.attrs["rows"] == 192
-    svc.close()
-
-
-def test_export_traces_writes_chrome_json(store, tmp_path):
-    svc = PredictionService(store)
-    svc.run(SQL)
-    path = tmp_path / "traces.json"
-    doc = svc.export_traces(str(path))
-    assert path.exists()
-    names = {e["name"] for e in doc["traceEvents"]}
-    assert "execute" in names and "process_name" in names
     svc.close()
 
 
@@ -452,4 +437,141 @@ def test_explain_without_analyze_renders_plan_only(store):
     text = ex.pretty()
     assert "scan [patient_info]" in text
     assert "actual time=" not in text
+    svc.close()
+
+
+# ---------------------------------------------------------------------------
+# 6. Profiler spans, device-op scopes and the launch counter
+# ---------------------------------------------------------------------------
+
+CHUNK = 64                           # 300 rows: 5 morsels, the tail padded
+
+
+def _profile_events(log_dir):
+    """(start, end, name, trace_id, line) of every ``repro.*`` event on the
+    host planes of the newest profile under ``log_dir``."""
+    (path,) = glob.glob(os.path.join(log_dir, "plugins", "profile", "*",
+                                     "*.xplane.pb"))
+    data = jax.profiler.ProfileData.from_file(path)
+    out = []
+    for plane in data.planes:
+        if not plane.name.startswith("/host:"):
+            continue
+        for i, line in enumerate(plane.lines):
+            for e in line.events:
+                if e.name.startswith("repro."):
+                    out.append((e.start_ns, e.start_ns + e.duration_ns,
+                                e.name[len("repro."):],
+                                dict(e.stats).get("trace_id"), i))
+    return out
+
+
+def _inside(inner, outer):
+    return outer[0] <= inner[0] and inner[1] <= outer[1] \
+        and inner[4] == outer[4]
+
+
+@pytest.mark.parametrize("telemetry", [True, False])
+def test_profiler_spans_of_a_chunked_query(store, tmp_path, telemetry):
+    """A cold then a warm chunked ``sql()`` under a CPU profiler session:
+    every phase reaches the host timeline as ``repro.<name>``, nested as
+    the executor runs them, tagged with its request's trace id; with
+    telemetry off too, and then with no registry write."""
+    svc = PredictionService(store, chunk_rows=CHUNK, telemetry=telemetry)
+    n_morsels = -(-store.get_table("patient_info").capacity // CHUNK)
+    with jax.profiler.trace(str(tmp_path)):
+        svc.sql(SQL)
+        svc.sql(SQL)
+    events = _profile_events(str(tmp_path))
+    ids = sorted({e[3] for e in events} - {0})
+    assert len(ids) == 2                      # one id per request
+    if telemetry:
+        assert ids == [t.trace_id for t in svc.traces()]
+    else:
+        assert svc.metrics.writes == 0 and svc.traces() == []
+    # the only span outside any request: the admission queue's drain
+    assert {e[2] for e in events if e[3] == 0} == {"admit"}
+    for i, rid in enumerate(ids):
+        mine = [e for e in events if e[3] == rid]
+        count = Counter(e[2] for e in mine)
+        assert count["parse"] == 1 and count["compile"] == 1
+        assert count["admit"] == 2            # offer, then the serve
+        assert count["execute"] == 1 and count["assemble"] == 1
+        assert count["morsel.slice"] == count["morsel.launch"] == n_morsels
+        assert count["device_wait"] >= 1
+        # the cold request optimizes and generates code inside `compile`
+        assert count["optimize"] == count["codegen"] == (1 - i)
+        (compile_,) = [e for e in mine if e[2] == "compile"]
+        (execute,) = [e for e in mine if e[2] == "execute"]
+        for e in mine:
+            if e[2] in ("optimize", "codegen"):
+                assert _inside(e, compile_)
+            if e[2].startswith("morsel.") or e[2] in ("assemble",
+                                                      "device_wait"):
+                assert _inside(e, execute)
+        assert compile_[1] <= execute[0]
+    svc.close()
+
+
+def _forest_join_store(n_rows=400, seed=5):
+    store = ModelStore()
+    for n, t in hospital_tables(n_rows, seed=seed).items():
+        store.register_table(n, t)
+    pi = store.get_table("patient_info")
+    bt = store.get_table("blood_tests")
+    feats = FEATS + ["hematocrit"]
+    order = np.argsort(np.asarray(bt.column("pid")))
+    data = {c: np.asarray(pi.column(c)) for c in FEATS}
+    data["hematocrit"] = np.asarray(bt.column("hematocrit"))[order]
+    pipe = Pipeline([StandardScaler(feats).fit(data)],
+                    RandomForest(n_trees=4, task="regression", max_depth=5),
+                    PipelineMetadata(name="rf", task="regression"))
+    pipe.fit(data, np.asarray(pi.column("length_of_stay")))
+    store.register_model("rf", pipe)
+    return store
+
+
+def test_device_ops_named_by_layer_in_hlo():
+    """The compiled join + forest plan's HLO metadata names each op's
+    layer: the join, the featurizer and the model step."""
+    store = _forest_join_store()
+    svc = PredictionService(store)
+    sql = ("SELECT pid, PREDICT(MODEL='rf') AS los FROM patient_info "
+           "JOIN blood_tests ON pid")
+    compiled = svc.compile(sql)
+    tabs = {t: store.get_table(t) for t in compiled.scan_tables}
+    hlo = compiled.fn.lower(tabs).compile().as_text()
+    for scope in ("repro.join", "repro.featurize", "repro.model"):
+        assert f"/{scope}/" in hlo, scope
+    svc.close()
+
+
+@pytest.mark.parametrize("n_rows", [300, 256])
+def test_launches_count_a_chunked_plan_by_hand(n_rows):
+    """patient_info's columns plus its validity mask are sliced in every
+    morsel and padded in a short tail; each morsel is one program call;
+    the output's columns and mask are concatenated, then trimmed unless
+    the morsels cover the rows exactly."""
+    store = _make_store(n_rows=n_rows)
+    svc = PredictionService(store, chunk_rows=CHUNK)
+    out = svc.sql(SQL)
+    arrays = len(store.get_table("patient_info").columns) + 1
+    outputs = len(out.columns) + 1           # pid, age and the mask: 3
+    assert outputs == 3
+    if n_rows == 300:                        # 5 morsels, the fifth short
+        want = 5 * arrays + arrays + 5 + 2 * outputs
+    else:                                    # 4 whole morsels
+        want = 4 * arrays + 4 + outputs
+    assert svc.stats.chunks_executed == n_rows // CHUNK + (n_rows == 300)
+    assert svc.stats.launches == want
+    svc.close()
+
+
+def test_launches_of_the_whole_path_is_one_program(store):
+    svc = PredictionService(store)
+    svc.sql(SQL)
+    svc.sql(SQL)
+    assert svc.stats.launches == 2
+    snap = svc.metrics_snapshot()
+    assert snap["counters"]["repro_launches_total"] == 2.0
     svc.close()
