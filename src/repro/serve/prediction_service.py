@@ -166,8 +166,8 @@ class ServiceStats:
     chunks_executed: int = 0
     launches: int = 0               # device programs the executor issued:
                                     # compiled-program calls plus the
-                                    # morsel loop's eager slices, pads,
-                                    # concats and trims
+                                    # morsel loop's slicer and assembler
+                                    # calls
     # result-cache tier
     result_hits: int = 0            # spliced executions served from cache
     result_misses: int = 0          # spliced executions that re-materialized
@@ -433,22 +433,23 @@ def _schema_sig(schema: Schema) -> Tuple:
     return tuple(sorted((c.name, str(c.dtype), c.dictionary)
                         for c in schema.columns))
 
-def _pad_table(table: Table, target: int) -> Table:
-    n = table.capacity
-    if n == target:
-        return table
-    pad = target - n
-    cols = {k: jnp.pad(v, [(0, pad)] + [(0, 0)] * (v.ndim - 1))
-            for k, v in table.columns.items()}
-    valid = jnp.pad(table.valid, (0, pad))        # False-padded
-    return Table(cols, valid, table.schema)
+def _morsel(table: Table, start: Any, size: int, rows: int) -> Table:
+    """Rows ``[start, start + rows)`` of ``table``, False-padded to ``size``
+    rows (pad rows zero).  ``start`` may be traced, so every full morsel
+    (``rows == size``) of a table shares one executable and only a short
+    tail adds a second."""
+    def cut(v):
+        part = jax.lax.dynamic_slice_in_dim(v, start, rows, axis=0)
+        if rows == size:
+            return part
+        return jnp.pad(part, [(0, size - rows)] + [(0, 0)] * (v.ndim - 1))
+    return Table({k: cut(v) for k, v in table.columns.items()},
+                 cut(table.valid), table.schema)
 
 
-def _slice_table(table: Table, start: int, size: int) -> Table:
-    end = min(start + size, table.capacity)
-    cols = {k: v[start:end] for k, v in table.columns.items()}
-    part = Table(cols, table.valid[start:end], table.schema)
-    return _pad_table(part, size)
+_morsel_jit = jax.jit(_morsel, static_argnames=("size", "rows"))
+# morsels sliced and launched ahead of the device: enough to keep it fed
+_MORSELS_IN_FLIGHT = 2
 
 
 def _slice_table_host(table: Table, start: int, size: int) -> Table:
@@ -499,15 +500,6 @@ def _stack_pad_host(tables: List[Table], target: int) -> Table:
     return Table(cols, jnp.asarray(valid), base.schema)
 
 
-def _n_arrays(value: Any) -> int:
-    """Device arrays in a table (its columns and validity mask) or a
-    matrix: the eager ops that one slice, pad, concat or trim of it
-    dispatches."""
-    if isinstance(value, Table):
-        return len(value.columns) + 1
-    return 1
-
-
 def _rows_of(out: Any) -> int:
     if isinstance(out, Table):
         return out.capacity
@@ -553,6 +545,15 @@ def _concat_outputs(pieces: List[Any]) -> Any:
         valid = jnp.concatenate([p.valid for p in pieces], axis=0)
         return Table(cols, valid, base.schema)
     return jnp.concatenate(pieces, axis=0)
+
+
+def _assemble(pieces: List[Any], n: int) -> Any:
+    """The morsels' outputs concatenated and trimmed to the ``n`` rows of
+    the input (a trim to the whole length is free)."""
+    return _trim_rows(_concat_outputs(pieces), n)
+
+
+_assemble_jit = jax.jit(_assemble, static_argnums=1)
 
 
 def _concat_outputs_host(pieces: List[Any]) -> Any:
@@ -792,6 +793,9 @@ class PredictionService:
         # (invalidation hooks clear it), and the optimizer copies its input
         # plan, so a cached parse is never mutated by compilation.
         self._parse_cache: Dict[str, Plan] = {}
+        # Morsel row offsets as device scalars, made once each: a Python
+        # int argument would cost a host-to-device copy on every slice.
+        self._offsets: Dict[int, jax.Array] = {}
         # Streaming ingest: table -> injected-clock time of its most recent
         # stats-stable append (the 'append' invalidation kind).  The
         # freshness-SLA tier compares a request's max_staleness_s budget
@@ -2632,46 +2636,52 @@ class PredictionService:
                          trace: Any = NULL_TRACE) -> Any:
         """Morsel execution: every chunk (tail included, via padding) has the
         same static shape, so XLA compiles one chunk executable total.
-        Other tables (a lookup join's build side) go in whole."""
+        Other tables (a lookup join's build side) go in whole.  Each morsel
+        is cut by one slicer call and the outputs joined by one assembler
+        call, both compiled unless the service runs eagerly."""
         name = compiled.morsel_table
         table = tabs[name]
         n = table.capacity
+        size = self.chunk_rows
+        slicer = _morsel_jit if self.jit else _morsel
+        assemble = _assemble_jit if self.jit else _assemble
         tid = trace.trace_id
-        trace.event("chunked", rows=n, chunk_rows=self.chunk_rows)
+        trace.event("chunked", rows=n, chunk_rows=size)
         pieces, captured = [], []
-        # one eager op per column and validity mask for each slice, tail
-        # pad, concat and trim (a trim to the whole length is free); one
-        # per program call
-        launches = 0
-        assembly = 2 if n % self.chunk_rows else 1
         t0 = time.perf_counter()
-        for start in range(0, n, self.chunk_rows):
+        for i, start in enumerate(range(0, n, size)):
+            if i >= _MORSELS_IN_FLIGHT:
+                # a host that outruns the device would otherwise hold
+                # every morsel's slice in device memory at once
+                with profile_span("device_wait", tid):
+                    jax.block_until_ready(pieces[i - _MORSELS_IN_FLIGHT])
+            offset = self._offsets.get(start)
+            if offset is None:
+                offset = self._offsets.setdefault(start, jnp.int32(start))
             with profile_span("morsel.slice", tid):
-                chunk = _slice_table(table, start, self.chunk_rows)
-            launches += _n_arrays(table) \
-                * (1 if start + self.chunk_rows <= n else 2)
+                chunk = slicer(table, offset, size=size,
+                               rows=min(size, n - start))
             with profile_span("morsel.launch", tid):
                 raw = compiled.fn({**tabs, name: chunk})
-            launches += 1
             if compiled.capture is not None:
                 pieces.append(raw[0])
                 captured.append(raw[1])
             else:
                 pieces.append(raw)
+        # one slicer and one program call per morsel, one assembler call
+        launches = 2 * len(pieces) + 1
         if compiled.capture is not None and captured and store_capture:
             # morsel_table holds for the capture root too, so its rows are
             # the morsel's rows and the pieces concatenate to exactly the
             # whole-table subtree value
             with profile_span("assemble", tid):
-                cap = jax.block_until_ready(
-                    _trim_rows(_concat_outputs(captured), n))
-            launches += assembly * _n_arrays(cap)
+                cap = jax.block_until_ready(assemble(captured, n))
+            launches += 1
             self._store_result(compiled.capture, cap,
                                time.perf_counter() - t0,
                                producer=compiled.key, tenant=tenant)
         with profile_span("assemble", tid):
-            out = _trim_rows(_concat_outputs(pieces), n)
-        launches += assembly * _n_arrays(out)
+            out = assemble(pieces, n)
         with self._lock:
             self.stats.chunks_executed += len(pieces)
             self.stats.launches += launches

@@ -27,7 +27,8 @@ from repro.ml import DecisionTree, Pipeline, PipelineMetadata, StandardScaler
 from repro.relational.table import Table
 from repro.relational.expr import col
 from repro.serve import PredictionService
-from repro.serve.prediction_service import _morsel_table
+from repro.serve.prediction_service import (_assemble_jit, _morsel,
+                                            _morsel_jit, _morsel_table)
 
 N_ROWS = 600
 FEATS = ["age", "gender", "pregnant", "rcount"]
@@ -332,6 +333,75 @@ def test_lookup_join_runs_in_morsels_bitwise(store):
     assert (v1 == v2).all()
     for k in c2:
         assert (c1[k] == c2[k]).all(), k
+
+
+MORSEL = 64
+
+
+def _store_of(n_rows):
+    store = ModelStore()
+    for n, t in hospital_tables(n_rows, seed=7).items():
+        store.register_table(n, t)
+    pi = store.get_table("patient_info")
+    data = {c: np.asarray(pi.column(c)) for c in pi.names}
+    store.register_model("los_pi", _pipeline(data))
+    return store
+
+
+@pytest.mark.parametrize("tail,result_cache,jit", [
+    (0, False, True),
+    (1, False, True),
+    (MORSEL - 1, False, True),
+    (1, True, True),                 # the capture branch assembles twice
+    (MORSEL - 1, False, False),      # eager: the same helpers, unjitted
+])
+def test_morsel_slicer_and_assembler(tail, result_cache, jit,
+                                     assert_tables_equal):
+    """Eight whole morsels and a ``tail``-row one: the chunked answer is
+    the whole-table answer bitwise, the tail's pad rows are zero with
+    valid False, and a second query traces, compiles and builds nothing
+    new, neither a plan nor a slicer or assembler executable."""
+    n_rows = 8 * MORSEL + tail
+    store = _store_of(n_rows)
+    chunked = PredictionService(store, jit=jit, chunk_rows=MORSEL,
+                                enable_result_cache=result_cache)
+    whole = PredictionService(store, jit=jit,
+                              enable_result_cache=result_cache)
+    out = chunked.run(SQL)
+    n_morsels = 8 + (tail > 0)
+    assert chunked.stats.chunks_executed == n_morsels
+    # a slicer and a program call a morsel, then one assembler call for
+    # the answer and one for the captured subtree
+    assert chunked.stats.launches == 2 * n_morsels + 1 + result_cache
+    assert (chunked.compile(SQL).capture is not None) == result_cache
+    if result_cache:
+        assert chunked.stats.result_puts == 1
+    assert_tables_equal(out, whole.run(SQL))
+
+    plans = codegen.compile_stats["plans_compiled"]
+    traces = chunked.stats.jit_traces
+    sizes = (_morsel_jit._cache_size(), _assemble_jit._cache_size())
+    again = chunked.run(SQL)
+    assert codegen.compile_stats["plans_compiled"] == plans
+    assert chunked.stats.jit_traces == traces
+    assert (_morsel_jit._cache_size(), _assemble_jit._cache_size()) == sizes
+    assert chunked.stats.chunks_executed == 2 * n_morsels
+    assert_tables_equal(again, out)
+
+    if tail:
+        pi = store.get_table("patient_info")
+        cut = _morsel_jit if jit else _morsel
+        last = cut(pi, 8 * MORSEL, size=MORSEL, rows=tail)
+        assert last.capacity == MORSEL
+        valid = np.asarray(last.valid)
+        assert valid[:tail].tolist() == np.asarray(pi.valid)[-tail:].tolist()
+        assert not valid[tail:].any()
+        for k, v in last.columns.items():
+            v = np.asarray(v)
+            assert (v[:tail] == np.asarray(pi.columns[k])[-tail:]).all(), k
+            assert (v[tail:] == 0).all(), k
+    chunked.close()
+    whole.close()
 
 
 # ---------------------------------------------------------------------------
