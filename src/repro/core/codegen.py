@@ -175,6 +175,22 @@ def _model_scores(model, x: jnp.ndarray) -> jnp.ndarray:
     raise ValueError(f"unknown model kind {kind}")
 
 
+def output_task(task: str, loss: Optional[str] = None) -> str:
+    """The task under which a model's scores become its ``PREDICT``
+    column (:func:`_scores_to_output`): the pipeline's, except where a
+    boosted ensemble's ``loss`` says otherwise.  Every runtime and tree
+    strategy maps through here.
+
+    A boosted ensemble's score is one margin.  Under ``log_loss`` it is a
+    logit: ``PREDICT_PROBA`` of a classification pipeline is its sigmoid
+    and ``PREDICT`` is ``margin > 0``.  Under ``squared_error`` it is the
+    prediction itself, returned as it is by ``PREDICT`` and
+    ``PREDICT_PROBA`` alike, whatever the pipeline's task (fitted to 0/1
+    labels it is a least-squares estimate of P(y = 1), to be thresholded
+    by the query).  ``loss`` is None for every other kind."""
+    return "regression" if loss == "squared_error" else task
+
+
 def _scores_to_output(scores: jnp.ndarray, task: str, proba: bool
                       ) -> jnp.ndarray:
     """[n, k] scores -> [n] prediction column."""
@@ -245,10 +261,11 @@ def _np_model_fn(model):
         trees, base, lr = list(model.trees), model.base, model.learning_rate
 
         def gbt(x):
-            out = np.full((x.shape[0],), base, np.float32)
+            # the float32 order of GradientBoostedTrees.predict
+            out = np.zeros((x.shape[0],), np.float32)
             for t in trees:
                 out = out + lr * _tree_scores_np(t, x)[:, 0]
-            return out[:, None]
+            return (out + base)[:, None]
         return gbt
     if kind in ("linear_regression", "logistic_regression"):
         w = np.asarray(model.weights, np.float32)
@@ -291,6 +308,7 @@ def _external_predict(model, task: str, proba: bool, latency_s: float):
     """Host-side (numpy) model evaluation behind a pure_callback — the
     Raven Ext / container execution path."""
     score_fn = _np_model_fn(model)
+    task = output_task(task, getattr(model, "loss", None))
 
     def host_fn(x: np.ndarray) -> np.ndarray:
         if latency_s > 0:
@@ -437,7 +455,8 @@ def compile_plan(plan: Plan, catalog,
                     proba = a.get("proba", False)
                     if n.runtime == "native":
                         scores = _model_scores(a["model"], x)
-                        env[nid] = _scores_to_output(scores, task, proba)
+                        env[nid] = _scores_to_output(scores, output_task(
+                            task, getattr(a["model"], "loss", None)), proba)
                     elif n.runtime == "external":
                         env[nid] = _external_predict(
                             a["model"], task, proba,
@@ -483,7 +502,8 @@ def compile_plan(plan: Plan, catalog,
                         scores = predict_ensemble_gemm(ens, ins[0])
                     scores = scores + a.get("bias", 0.0)
                     env[nid] = _scores_to_output(
-                        scores, a.get("task", "classification"),
+                        scores, output_task(a.get("task", "classification"),
+                                            a.get("loss")),
                         a.get("proba", False))
                 elif op == "constant_vector":
                     n_rows = ins[0].shape[0] \

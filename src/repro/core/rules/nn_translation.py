@@ -16,6 +16,18 @@ from ..ir import Category, Node, Plan
 from .common import find_predict_chains
 
 
+def tree_slots(trees) -> int:
+    """The dense form's work per row at each tree's own size: ``I`` gates,
+    ``I * L`` path-count products and ``L`` payouts (``I`` internal nodes,
+    ``L`` leaves), summed over the trees."""
+    total = 0
+    for t in trees:
+        n_leaves = len(t.leaf_indices())
+        n_internal = t.n_nodes - n_leaves
+        total += n_internal + n_internal * n_leaves + n_leaves
+    return total
+
+
 def _translate_trees(plan, chain, cfg, report,
                      strategy: str = "gemm") -> bool:
     from ...ml.hummingbird import ensemble_to_gemm
@@ -30,26 +42,31 @@ def _translate_trees(plan, chain, cfg, report,
     else:  # gbt
         trees, average = model.trees, False
         bias, scale = model.base, model.learning_rate
-        task = "regression"
     # The Pallas kernel needs full 128-lane MXU tiles; the gather-gated dense
     # strategy has no alignment requirement and wastes flops on padding.
     pad = 128 if strategy == "pallas" else cfg.gemm_pad_to
     ens = ensemble_to_gemm(trees, pad_to=pad, average=average)
     if scale != 1.0:
         ens.e = (ens.e * scale).astype(np.float32)
+    n_i, n_l = ens.c.shape[1], ens.c.shape[2]
+    # (real, padded): tree slots per row as fitted, and as the padded
+    # ensemble computes them (ServiceStats.tree_slots*, EXPLAIN)
+    slots = (tree_slots(trees), ens.n_trees * (n_i + n_i * n_l + n_l))
+    attrs = {"ensemble": ens, "task": task, "proba": proba,
+             "bias": bias, "strategy": strategy, "slots": slots,
+             "model_name": chain.predict.attrs.get("model_name")}
+    if kind == "gbt":
+        attrs["loss"] = model.loss
     node = Node(op="tree_gemm", category=Category.LA,
-                inputs=[chain.featurize.id],
-                attrs={"ensemble": ens, "task": task, "proba": proba,
-                       "bias": bias, "strategy": strategy,
-                       "model_name": chain.predict.attrs.get("model_name")},
-                out_kind="matrix")
+                inputs=[chain.featurize.id], attrs=attrs, out_kind="matrix")
     plan.add(node)
     plan.rewire(chain.predict.id, node.id)
     plan.prune_dead()
     report.log("nn_translation",
                f"{chain.predict.attrs.get('model_name')}: {kind} -> "
-               f"tree_gemm/{strategy} [{ens.a.shape[0]}x{ens.a.shape[2]}i/"
-               f"{ens.c.shape[2]}l pad {pad}]")
+               f"tree_gemm/{strategy} [{ens.a.shape[0]}x{n_i}i/"
+               f"{n_l}l pad {pad}; slots {slots[0]} real, "
+               f"{slots[1]} padded]")
     return True
 
 

@@ -387,16 +387,32 @@ class RandomForest:
 
 
 class GradientBoostedTrees:
-    """Squared-loss gradient boosting (regression / binary via logits)."""
+    """Gradient-boosted trees: a margin ``base + learning_rate * sum_t
+    leaf_t(x)`` and the loss that states what the margin means.
+
+    ``loss="squared_error"`` is this class's own ``fit`` (base = the mean,
+    each tree fitted to the residual): the margin is the prediction.
+    ``loss="log_loss"`` is a binary classifier whose margin is a logit,
+    for ensembles trained by a library and loaded here (``trees``,
+    ``base`` and ``learning_rate`` set from its arrays; ``fit`` refuses
+    it).  How ``PREDICT`` turns the margin into an answer is decided in
+    one place for every runtime and strategy: ``core.codegen.output_task``.
+    """
 
     kind = "gbt"
+    LOSSES = ("squared_error", "log_loss")
 
     def __init__(self, n_trees: int = 20, max_depth: int = 4,
-                 learning_rate: float = 0.2, min_leaf: int = 5):
+                 learning_rate: float = 0.2, min_leaf: int = 5,
+                 loss: str = "squared_error"):
+        if loss not in self.LOSSES:
+            raise ValueError(f"loss must be one of {self.LOSSES}, "
+                             f"got {loss!r}")
         self.n_trees = n_trees
         self.max_depth = max_depth
         self.learning_rate = learning_rate
         self.min_leaf = min_leaf
+        self.loss = loss
         self.trees: List[TreeArrays] = []
         self.base: float = 0.0
         self.feature_names: Optional[List[str]] = None
@@ -404,6 +420,9 @@ class GradientBoostedTrees:
     def fit(self, x: np.ndarray, y: np.ndarray,
             feature_names: Optional[Sequence[str]] = None
             ) -> "GradientBoostedTrees":
+        if self.loss != "squared_error":
+            raise ValueError("fit trains squared_error only; load a "
+                             f"{self.loss} ensemble from its arrays")
         y = np.asarray(y, np.float64)
         self.base = float(y.mean())
         pred = np.full_like(y, self.base)
@@ -419,8 +438,11 @@ class GradientBoostedTrees:
         return self
 
     def predict(self, x: jnp.ndarray) -> jnp.ndarray:
+        """The margin [n]: the scaled leaf values summed in tree order,
+        then the base, the float32 operation order of the dense strategy
+        (``hummingbird.predict_ensemble_gemm``)."""
         x = jnp.asarray(x, jnp.float32)
-        out = jnp.full((x.shape[0],), self.base, jnp.float32)
+        out = jnp.zeros((x.shape[0],), jnp.float32)
         for t in self.trees:
             out = out + self.learning_rate * t.predict_jnp(x)[:, 0]
-        return out
+        return out + self.base

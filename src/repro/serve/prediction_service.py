@@ -168,6 +168,11 @@ class ServiceStats:
                                     # compiled-program calls plus the
                                     # morsel loop's slicer and assembler
                                     # calls
+    tree_slots: int = 0             # tree-layer work: each executed plan's
+                                    # tree slots per row (the tree_gemm
+                                    # nodes' fitted sizes) times its rows
+    tree_slots_padded: int = 0      # the same at the sizes the padded
+                                    # ensemble computes
     # result-cache tier
     result_hits: int = 0            # spliced executions served from cache
     result_misses: int = 0          # spliced executions that re-materialized
@@ -351,6 +356,16 @@ class CompiledPrediction:
     # Table the plan can run in row morsels of (``chunk_rows``): its
     # output rows are this table's rows one for one (see _morsel_table).
     morsel_table: Optional[str] = None
+    # (real, padded) tree slots per row of the plan's tree_gemm nodes
+    # (``nn_translation.tree_slots``); zero without one
+    tree_slots: Tuple[int, int] = dataclasses.field(init=False,
+                                                    default=(0, 0))
+
+    def __post_init__(self):
+        slots = [n.attrs["slots"] for n in self.plan.nodes.values()
+                 if n.op == "tree_gemm"]
+        self.tree_slots = (sum(r for r, _ in slots),
+                           sum(p for _, p in slots))
 
     @property
     def chunk_table(self) -> Optional[str]:
@@ -358,6 +373,23 @@ class CompiledPrediction:
         row-local end to end, as partition sharding and request stacking
         need."""
         return self.morsel_table if len(self.scan_tables) == 1 else None
+
+
+def _tree_work(compiled: CompiledPrediction, tabs: Dict[str, Table]
+               ) -> Tuple[int, int]:
+    """(real, padded) tree slots an execution over ``tabs`` scores: the
+    plan's slots per row times the rows of its morsel table, else of its
+    largest scanned table, padding rows included."""
+    real, padded = compiled.tree_slots
+    if not real:
+        return 0, 0
+    name = compiled.morsel_table
+    if name in tabs:
+        rows = tabs[name].capacity
+    else:
+        rows = max((tabs[t].capacity for t in compiled.scan_tables
+                    if t in tabs), default=0)
+    return real * rows, padded * rows
 
 
 class PredictionTicket:
@@ -1961,8 +1993,11 @@ class PredictionService:
         if params:
             tabs["__params__"] = params
         compiled.serves += 1
+        real, padded = _tree_work(compiled, tabs)
         with self._lock:
             self.stats.batch_executions += 1
+            self.stats.tree_slots += real
+            self.stats.tree_slots_padded += padded
         if compiled.splice is not None:
             out = self._execute_spliced(compiled, tabs, ctx=ctx,
                                         trace=trace)
@@ -3091,9 +3126,12 @@ class PredictionService:
         (a padded stack is not the catalog data the result-cache key would
         claim)."""
         compiled.serves += 1
+        real, padded = _tree_work(compiled, tabs)
         with self._lock:
             self.stats.batch_executions += 1
             self.stats.launches += 1
+            self.stats.tree_slots += real
+            self.stats.tree_slots_padded += padded
         raw = compiled.fn(tabs)
         if compiled.capture is not None:
             raw = raw[0]

@@ -66,6 +66,25 @@ def test_dense_tree_lowering_compiles_for_v5e(one_chip):
     assert mem.temp_size_in_bytes < 8 << 30
 
 
+def test_boosted_dense_lowering_compiles_whole_table_for_v5e(one_chip):
+    """``flights_gbt.batch``'s program: 500 depth-8 trees padded to 200
+    internal nodes and 208 leaves over the whole 483,000-row table in one
+    call (no morsels), summed unaveraged, plus the base, then the
+    sigmoid of ``PREDICT_PROBA``."""
+    n, f, t, i, l = 483000, 7, 500, 200, 208
+    s = _shapes(one_chip, n, f, t, i, l)
+
+    def boosted(x, b, c, d, e, feat):
+        ens = EnsembleGemm(a=None, b=b, c=c, d=d, e=e, n_trees=t,
+                           average=False, feat=feat)
+        return jax.nn.sigmoid(predict_ensemble_gemm(ens, x)[:, 0] - 1.25)
+
+    compiled = jax.jit(boosted).lower(s["x"], s["b"], s["c"], s["d"],
+                                      s["e"], s["feat"]).compile()
+    # the whole-table working set a quarter of the HBM admits
+    assert compiled.memory_analysis().temp_size_in_bytes < 4 << 30
+
+
 def test_train_step_compile_options_accepted_for_v5e(one_chip):
     """libtpu refuses a compile option it does not know, so an unknown key
     in the train loop's TPU options would fail the first step."""
