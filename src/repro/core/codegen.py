@@ -37,7 +37,8 @@ from .ir import Plan, plan_params
 
 __all__ = ["compile_plan", "execute", "resolve_params", "ExecutionConfig",
            "compile_stats", "reset_compile_stats", "add_compile_listener",
-           "add_trace_listener", "pow2_bucket", "count_jit_trace"]
+           "add_trace_listener", "pow2_bucket", "count_jit_trace",
+           "COMPACT_ROWS", "model_steps", "compaction_sites"]
 
 # XLA's CPU client owns a worker pool sized by the host's core count.  On a
 # one-core host that single worker executes the whole computation — including
@@ -173,6 +174,89 @@ def _model_scores(model, x: jnp.ndarray) -> jnp.ndarray:
     if kind == "mlp":
         return model.predict_scores(x)
     raise ValueError(f"unknown model kind {kind}")
+
+
+# Live-row compaction: rows a compacted model step scores per loop step.
+# The middle of 8,192, 16,384 and 32,768; which is the smallest at which
+# the dense tree GEMM keeps its per-row time on a v5e is still to be
+# measured (PERF.md, section 7).
+COMPACT_ROWS = 16384
+
+
+def model_steps(plan: Plan) -> Dict[str, Optional[str]]:
+    """Every model step of ``plan`` (a node that reads a ``featurize``
+    output), in execution order, mapped to the table node whose live rows
+    are all it scores, or to None where it scores every row.
+
+    A native ``predict_model`` or ``tree_gemm`` is compacted when its
+    featurized table has a ``filter`` on its path from the scans: a filter
+    only narrows the validity mask, so without compaction the model would
+    score every row the filter dropped.  External and container runtimes
+    and the linear-algebra chains of translated linear models and MLPs
+    score every row."""
+    steps: Dict[str, Optional[str]] = {}
+    nodes = plan.nodes
+    for nid in plan.topo_order():
+        n = nodes[nid]
+        if not n.inputs or nodes[n.inputs[0]].op != "featurize":
+            continue
+        table = nodes[n.inputs[0]].inputs[0]
+        native = n.op == "tree_gemm" or (n.op == "predict_model"
+                                         and n.runtime == "native")
+        steps[nid] = table if native and _filtered(plan, table) else None
+    return steps
+
+
+def compaction_sites(plan: Plan) -> Dict[str, str]:
+    """The compacted model steps of ``plan``: node id -> the table node
+    whose validity gates the rows it scores (:func:`model_steps`)."""
+    return {nid: t for nid, t in model_steps(plan).items() if t is not None}
+
+
+def _filtered(plan: Plan, nid: str) -> bool:
+    """Whether a ``filter`` lies on some path from the scans to ``nid``."""
+    stack, seen = [nid], set()
+    while stack:
+        cur = stack.pop()
+        if cur in seen:
+            continue
+        seen.add(cur)
+        n = plan.nodes[cur]
+        if n.op == "filter":
+            return True
+        stack.extend(n.inputs)
+    return False
+
+
+def _score_live_rows(score: Callable[[jnp.ndarray], jnp.ndarray],
+                     x: jnp.ndarray, valid: jnp.ndarray
+                     ) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """``score`` over only the rows of ``x`` that ``valid`` keeps: their
+    indices are compacted, and a loop scores ``ceil(live / C)`` chunks of C
+    rows (C is ``COMPACT_ROWS`` clamped to the rows), gathering each chunk
+    and scattering its scores back into an ``[n, k]`` result of zeros.
+    Rows are scored independently, so every live row's score is the one
+    ``score(x)`` gives it.  Returns the scores and the rows scored (chunks
+    times C, an int32 scalar)."""
+    n = x.shape[0]
+    if n == 0:
+        return score(x), jnp.int32(0)
+    c = min(COMPACT_ROWS, n)
+    steps = -(-n // c)
+    live = jnp.sum(valid, dtype=jnp.int32)
+    idx = jnp.nonzero(valid, size=steps * c, fill_value=n)[0]
+    out = jax.eval_shape(score, jax.ShapeDtypeStruct((c,) + x.shape[1:],
+                                                     x.dtype))
+
+    def chunk(i, acc):
+        rows = jax.lax.dynamic_slice_in_dim(idx, i * c, c)
+        part = score(x.at[rows].get(mode="clip", indices_are_sorted=True))
+        return acc.at[rows].set(part, mode="drop", indices_are_sorted=True)
+
+    chunks = (live + c - 1) // c
+    scores = jax.lax.fori_loop(
+        0, chunks, chunk, jnp.zeros((n,) + out.shape[1:], out.dtype))
+    return scores, chunks * c
 
 
 def output_task(task: str, loss: Optional[str] = None) -> str:
@@ -340,7 +424,8 @@ def compile_plan(plan: Plan, catalog,
                  config: Optional[ExecutionConfig] = None,
                  capture: Optional[str] = None,
                  node_hook: Optional[Callable[[str, Any, Any, float],
-                                              None]] = None
+                                              None]] = None,
+                 count_scored: bool = False
                  ) -> Callable[[Dict[str, Table]], Any]:
     """Build the executable closure for ``plan``.
 
@@ -365,6 +450,14 @@ def compile_plan(plan: Plan, catalog,
     the EXPLAIN ANALYZE seam — only meaningful *un-jitted* (under jit the
     values are tracers and the timings are trace-time, not run-time), so
     the serving layer runs profiled executions eagerly.
+
+    Model steps whose input a filter narrowed score only the live rows
+    (:func:`compaction_sites`, ``_score_live_rows``); their output keeps
+    one row per input row.  With ``count_scored`` the function then
+    returns ``(result, scored)``, ``scored`` an int32 vector of the rows
+    each compacted step scored, and carries ``compacted``: each step's
+    ``(real, padded)`` tree slots per row, in the vector's order.  A plan
+    with no compacted step returns its result alone either way.
     """
     config = config or ExecutionConfig()
     compile_stats["plans_compiled"] += 1
@@ -379,9 +472,18 @@ def compile_plan(plan: Plan, catalog,
     parametric = {nid for nid in order
                   if nodes[nid].op in ("filter", "map")
                   and plan_params(plan, [nid])}
+    sites = compaction_sites(plan)
 
     def run(tables: Dict[str, Table]) -> Any:
         env: Dict[str, Any] = {}
+        scored: List[jnp.ndarray] = []
+
+        def scores_of(nid, score, x):
+            if nid not in sites:
+                return score(x)
+            out, rows = _score_live_rows(score, x, env[sites[nid]].valid)
+            scored.append(rows)
+            return out
 
         def bound(expr):
             try:
@@ -454,7 +556,8 @@ def compile_plan(plan: Plan, catalog,
                     task = a.get("task", "classification")
                     proba = a.get("proba", False)
                     if n.runtime == "native":
-                        scores = _model_scores(a["model"], x)
+                        scores = scores_of(nid, functools.partial(
+                            _model_scores, a["model"]), x)
                         env[nid] = _scores_to_output(scores, output_task(
                             task, getattr(a["model"], "loss", None)), proba)
                     elif n.runtime == "external":
@@ -495,12 +598,13 @@ def compile_plan(plan: Plan, catalog,
                     # executable.
                     strategy = a.get("strategy", "gemm")
                     if config.use_pallas_tree_gemm or strategy == "pallas":
-                        from ..kernels.tree_gemm import ops as tg_ops
-                        scores = tg_ops.tree_gemm(ens, ins[0])
+                        from ..kernels.tree_gemm.ops import tree_gemm
                     else:
-                        from ..ml.hummingbird import predict_ensemble_gemm
-                        scores = predict_ensemble_gemm(ens, ins[0])
-                    scores = scores + a.get("bias", 0.0)
+                        from ..ml.hummingbird import \
+                            predict_ensemble_gemm as tree_gemm
+                    bias = a.get("bias", 0.0)
+                    scores = scores_of(
+                        nid, lambda v: tree_gemm(ens, v) + bias, ins[0])
                     env[nid] = _scores_to_output(
                         scores, output_task(a.get("task", "classification"),
                                             a.get("loss")),
@@ -529,10 +633,15 @@ def compile_plan(plan: Plan, catalog,
             if node_hook is not None:
                 env[nid] = jax.block_until_ready(env[nid])
                 node_hook(nid, n, env[nid], time.perf_counter() - t0)
-        if capture is not None:
-            return env[plan.output], env[capture]
-        return env[plan.output]
+        out = env[plan.output] if capture is None \
+            else (env[plan.output], env[capture])
+        if count_scored and scored:
+            return out, jnp.stack(scored)
+        return out
 
+    if count_scored and sites:
+        run.compacted = tuple(nodes[nid].attrs.get("slots", (0, 0))
+                              for nid in sites)
     return run
 
 

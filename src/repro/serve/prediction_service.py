@@ -106,10 +106,11 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..core import codegen
 from ..core.codegen import (ExecutionConfig, add_compile_listener,
                             add_trace_listener, bind_structural_params,
-                            compile_plan, count_jit_trace, pow2_bucket,
-                            resolve_params)
+                            compile_plan, count_jit_trace, model_steps,
+                            pow2_bucket, resolve_params)
 from ..core.ir import (Node, Plan, ROW_LOCAL_OPS, bucketed_signature,
                        is_deterministic_subtree, plan_params, plan_signature,
                        sharded_signature, subtree_nodes, subtree_signatures)
@@ -170,9 +171,15 @@ class ServiceStats:
                                     # calls
     tree_slots: int = 0             # tree-layer work: each executed plan's
                                     # tree slots per row (the tree_gemm
-                                    # nodes' fitted sizes) times its rows
+                                    # nodes' fitted sizes) times the rows
+                                    # each scored
     tree_slots_padded: int = 0      # the same at the sizes the padded
                                     # ensemble computes
+    model_rows: int = 0             # rows of each model step's input table
+                                    # an execution read
+    model_rows_scored: int = 0      # the rows those steps scored: all of
+                                    # them, or for a step compacted to its
+                                    # live rows its chunks times C
     # result-cache tier
     result_hits: int = 0            # spliced executions served from cache
     result_misses: int = 0          # spliced executions that re-materialized
@@ -356,14 +363,22 @@ class CompiledPrediction:
     # Table the plan can run in row morsels of (``chunk_rows``): its
     # output rows are this table's rows one for one (see _morsel_table).
     morsel_table: Optional[str] = None
-    # (real, padded) tree slots per row of the plan's tree_gemm nodes
-    # (``nn_translation.tree_slots``); zero without one
+    # The plan's model steps (``codegen.model_steps``), those that score
+    # every row, and the (real, padded) tree slots per row of the tree_gemm
+    # nodes among the latter (``nn_translation.tree_slots``).  Compacted
+    # steps report the rows they scored from the program itself.
+    model_steps: int = dataclasses.field(init=False, default=0)
+    dense_steps: int = dataclasses.field(init=False, default=0)
     tree_slots: Tuple[int, int] = dataclasses.field(init=False,
                                                     default=(0, 0))
 
     def __post_init__(self):
-        slots = [n.attrs["slots"] for n in self.plan.nodes.values()
-                 if n.op == "tree_gemm"]
+        steps = model_steps(self.plan)
+        dense = [self.plan.nodes[nid] for nid, t in steps.items()
+                 if t is None]
+        slots = [n.attrs["slots"] for n in dense if n.op == "tree_gemm"]
+        self.model_steps = len(steps)
+        self.dense_steps = len(dense)
         self.tree_slots = (sum(r for r, _ in slots),
                            sum(p for _, p in slots))
 
@@ -375,21 +390,16 @@ class CompiledPrediction:
         return self.morsel_table if len(self.scan_tables) == 1 else None
 
 
-def _tree_work(compiled: CompiledPrediction, tabs: Dict[str, Table]
-               ) -> Tuple[int, int]:
-    """(real, padded) tree slots an execution over ``tabs`` scores: the
-    plan's slots per row times the rows of its morsel table, else of its
-    largest scanned table, padding rows included."""
-    real, padded = compiled.tree_slots
-    if not real:
-        return 0, 0
+def _model_input_rows(compiled: CompiledPrediction,
+                      tabs: Dict[str, Table]) -> int:
+    """Rows an execution over ``tabs`` feeds each model step: those of the
+    plan's morsel table, else of its largest scanned table, padding rows
+    included."""
     name = compiled.morsel_table
     if name in tabs:
-        rows = tabs[name].capacity
-    else:
-        rows = max((tabs[t].capacity for t in compiled.scan_tables
-                    if t in tabs), default=0)
-    return real * rows, padded * rows
+        return tabs[name].capacity
+    return max((tabs[t].capacity for t in compiled.scan_tables
+                if t in tabs), default=0)
 
 
 class PredictionTicket:
@@ -711,7 +721,7 @@ class ExplainResult:
         return [(nid, self.plan.nodes[nid])
                 for nid in self.plan.topo_order()]
 
-    def _detail(self, n: Node) -> str:
+    def _detail(self, n: Node, steps: Dict[str, Optional[str]]) -> str:
         a = n.attrs
         bits: List[str] = []
         if n.op == "scan":
@@ -745,15 +755,19 @@ class ExplainResult:
             bits.append(f"spliced sig={str(a.get('sig'))[:12]}")
         elif n.op == "attach_column":
             bits.append(str(a.get("name")))
+        if n.id in steps:
+            bits.append(f"live rows in chunks of {codegen.COMPACT_ROWS}"
+                        if steps[n.id] is not None else "every row")
         return f" [{', '.join(bits)}]" if bits else ""
 
     def pretty(self) -> str:
         lines: List[str] = []
         plan = self.plan
+        steps = model_steps(plan)
 
         def render(nid: str, prefix: str, is_last: bool, is_root: bool):
             n = plan.nodes[nid]
-            label = f"{n.op}{self._detail(n)}"
+            label = f"{n.op}{self._detail(n, steps)}"
             if nid in self.samples:
                 dt, rows = self.samples[nid]
                 label += f"  (actual time={dt * 1e3:.3f}ms rows={rows})"
@@ -828,6 +842,10 @@ class PredictionService:
         # Morsel row offsets as device scalars, made once each: a Python
         # int argument would cost a host-to-device copy on every slice.
         self._offsets: Dict[int, jax.Array] = {}
+        # (tree slots per compacted step, rows each scored) of the programs
+        # run since the last ``_count_model_work``: device vectors, read
+        # only once an execution has been waited for
+        self._scored: List[Tuple[Tuple, Any]] = []
         # Streaming ingest: table -> injected-clock time of its most recent
         # stats-stable append (the 'append' invalidation kind).  The
         # freshness-SLA tier compares a request's max_staleness_s budget
@@ -1478,7 +1496,7 @@ class PredictionService:
         shape-bucket tests bound (``jit_traces <= #buckets + #signatures``).
         With ``jit=False`` nothing traces, so nothing counts."""
         if not self.jit:
-            return fn
+            return self._counting(fn)
 
         def traced(tables):
             count_jit_trace()
@@ -1486,7 +1504,48 @@ class PredictionService:
                 self.stats.jit_traces += 1
             return fn(tables)
 
-        return jax.jit(traced)
+        return self._counting(fn, jax.jit(traced))
+
+    def _counting(self, fn, call=None):
+        """``call`` (``fn`` itself by default) as the serving paths call it.
+        Where ``fn`` compacts model steps (``compile_plan(...,
+        count_scored=True)``) its programs return ``(result, scored)``:
+        the caller gets the result, and the rows scored wait in
+        ``self._scored`` for ``_count_model_work``."""
+        call = fn if call is None else call
+        slots = getattr(fn, "compacted", None)
+        if slots is None:
+            return call
+
+        def counted(tables):
+            out, scored = call(tables)
+            with self._lock:
+                self._scored.append((slots, scored))
+            return out
+
+        return counted
+
+    def _count_model_work(self, compiled: CompiledPrediction,
+                          rows: int) -> None:
+        """Add one waited-for execution's model work to the stats: each
+        model step read ``rows`` rows; a step that scores every row scored
+        them all, a compacted step what its programs returned."""
+        with self._lock:
+            pending, self._scored = self._scored, []
+        scored = real = padded = 0
+        counts = jax.device_get([c for _, c in pending]) if pending else []
+        for (slots, _), count in zip(pending, counts):
+            for (r, p), k in zip(slots, count.tolist()):
+                scored += k
+                real += r * k
+                padded += p * k
+        r0, p0 = compiled.tree_slots
+        with self._lock:
+            st = self.stats
+            st.model_rows += rows * compiled.model_steps
+            st.model_rows_scored += rows * compiled.dense_steps + scored
+            st.tree_slots += r0 * rows + real
+            st.tree_slots_padded += p0 * rows + padded
 
     # -- compile cache -------------------------------------------------------
     def compile(self, query: Union[str, Plan],
@@ -1584,7 +1643,8 @@ class PredictionService:
             raw_fn = compile_plan(exec_plan, self.catalog,
                                   self.execution_config,
                                   capture=capture_ref.subtree_plan.output
-                                  if capture_ref is not None else None)
+                                  if capture_ref is not None else None,
+                                  count_scored=True)
             fn = self._jit(raw_fn)
         scans = _scan_names(exec_plan)
         morsel_table = _morsel_table(
@@ -1693,7 +1753,8 @@ class PredictionService:
                     part_tables=stage_scans(local_plan, anchor),
                     local_plan=local_plan,
                     local_raw_fn=compile_plan(local_plan, self.catalog,
-                                              self.execution_config),
+                                              self.execution_config,
+                                              count_scored=True),
                     local_sig=plan_signature(local_plan),
                     n_joins=stage_joins(local_plan), exchange=exchange))
             residual.prune_dead()
@@ -1809,7 +1870,8 @@ class PredictionService:
                            ) -> CompiledPrediction:
         t0 = time.perf_counter()
         residual = self._residual_plan(hit.plan, ref.subtree_plan.output, ref)
-        raw_fn = compile_plan(residual, self.catalog, self.execution_config)
+        raw_fn = compile_plan(residual, self.catalog, self.execution_config,
+                              count_scored=True)
         fn = self._jit(raw_fn)
         hit.report.log("result_cache",
                        f"upgraded to spliced {ref.describe()}")
@@ -1993,11 +2055,9 @@ class PredictionService:
         if params:
             tabs["__params__"] = params
         compiled.serves += 1
-        real, padded = _tree_work(compiled, tabs)
+        rows = _model_input_rows(compiled, tabs)
         with self._lock:
             self.stats.batch_executions += 1
-            self.stats.tree_slots += real
-            self.stats.tree_slots_padded += padded
         if compiled.splice is not None:
             out = self._execute_spliced(compiled, tabs, ctx=ctx,
                                         trace=trace)
@@ -2015,7 +2075,9 @@ class PredictionService:
         # host callbacks under async dispatch, and letting those trail the
         # ticket resolution deadlocks against the caller's next dispatch.
         with profile_span("device_wait", trace.trace_id):
-            return jax.block_until_ready(out)
+            out = jax.block_until_ready(out)
+        self._count_model_work(compiled, rows)
+        return out
 
     def _execute_whole(self, compiled: CompiledPrediction,
                        tabs: Dict[str, Table],
@@ -2558,7 +2620,8 @@ class PredictionService:
             # residual per append would put an XLA compile back on the
             # very path the delta tier exists to keep compile-free.
             if from_prefix and compiled.raw_fn is not None:
-                return compiled.raw_fn({**tabs, ref.slot: value})
+                return self._counting(compiled.raw_fn)(
+                    {**tabs, ref.slot: value})
             with self._lock:
                 self.stats.launches += 1
             return compiled.fn({**tabs, ref.slot: value})
@@ -3126,17 +3189,16 @@ class PredictionService:
         (a padded stack is not the catalog data the result-cache key would
         claim)."""
         compiled.serves += 1
-        real, padded = _tree_work(compiled, tabs)
         with self._lock:
             self.stats.batch_executions += 1
             self.stats.launches += 1
-            self.stats.tree_slots += real
-            self.stats.tree_slots_padded += padded
         raw = compiled.fn(tabs)
         if compiled.capture is not None:
             raw = raw[0]
         with profile_span("device_wait", trace.trace_id):
-            return jax.block_until_ready(raw)
+            raw = jax.block_until_ready(raw)
+        self._count_model_work(compiled, _model_input_rows(compiled, tabs))
+        return raw
 
     def _serve_stacked(self, compiled: CompiledPrediction,
                        group: List[_Pending],

@@ -92,3 +92,30 @@ def test_train_step_compile_options_accepted_for_v5e(one_chip):
     compiled = jax.jit(lambda a: a @ a, compiler_options=_TPU_OPTIONS
                        ).lower(x).compile()
     assert compiled.as_text()
+
+
+def test_compacted_dense_lowering_compiles_for_v5e(one_chip):
+    """``los_rf.batch``'s model step behind its WHERE: 100 depth-10 trees
+    over the live rows of one 262,144-row morsel, in chunks of
+    ``COMPACT_ROWS`` (``codegen._score_live_rows``).  Its temporaries are
+    sized by the chunk, so they come out under those of the same trees
+    over every row of the morsel."""
+    from repro.core.codegen import _score_live_rows
+    n, f, t, i, l = 1 << 18, 6, 100, 1024, 1024
+    s = _shapes(one_chip, n, f, t, i, l)
+    valid = jax.ShapeDtypeStruct((n,), jnp.bool_, sharding=one_chip)
+
+    def trees(x, b, c, d, e, feat):
+        ens = EnsembleGemm(a=None, b=b, c=c, d=d, e=e, n_trees=t,
+                           feat=feat)
+        return predict_ensemble_gemm(ens, x)
+
+    def compacted(x, valid, b, c, d, e, feat):
+        return _score_live_rows(lambda v: trees(v, b, c, d, e, feat), x,
+                                valid)
+
+    args = (s["b"], s["c"], s["d"], s["e"], s["feat"])
+    live = jax.jit(compacted).lower(s["x"], valid, *args).compile()
+    every = jax.jit(trees).lower(s["x"], *args).compile()
+    assert live.memory_analysis().temp_size_in_bytes \
+        < every.memory_analysis().temp_size_in_bytes
